@@ -51,13 +51,13 @@ Three modes:
     PYTHONPATH=src python benchmarks/serve_bench.py [--ab | --spec | --share]
         [--fast] [--dry-run] [--out serve_bench.json]
 
-``--compile-cache DIR`` points JAX's persistent compilation cache at DIR:
-run the same bench twice and the second run measures *steady-state*
-serving (compiles replayed from disk) instead of cold start.  The 20-
-request cold run is compile-bound — the paged/spec arms compile several
-times more programs (per-bucket chunk steps, per-Q verify) than flat, so
-cold-start wall-clock understates them; records made with a warm cache
-carry ``"compile_cache": DIR`` so the two regimes are never conflated.
+JAX's persistent compilation cache is placed by
+`repro.launch.cache.init_compile_cache` (``JAX_COMPILATION_CACHE_DIR`` if
+set, else ``.jax_cache/`` at the checkout root): run the same bench twice
+and the second run replays compiles from disk.  The 20-request cold run is
+compile-bound — the paged/spec arms compile several times more programs
+(per-bucket chunk steps, per-Q verify) than flat, so cold-start wall-clock
+understates them.
 """
 from __future__ import annotations
 
@@ -68,6 +68,7 @@ import numpy as np
 
 from repro.configs import get_config, smoke_variant
 from repro.core import ElasticScalingPolicy, ScaleEvent
+from repro.launch.cache import init_compile_cache
 from repro.obs import (Tracer, dominant_host_phase, host_overlap_ratio,
                        phase_attribution)
 from repro.serve import (DisaggEngine, FaultInjector, FaultPlan,
@@ -704,7 +705,7 @@ def _tick_run(engine, reqs, *, max_ticks: int):
     """Drive an engine on an injected tick clock (1 tick = 1 simulated
     second) so TTFT/TPOT — and therefore SLO attainment and goodput — are
     deterministic instead of wall-clock noise."""
-    from repro.compat import set_mesh
+    from jax import set_mesh
     engine.submit(reqs)
     with set_mesh(engine.mesh):
         while (engine.scheduler.has_pending or engine._by_slot
@@ -879,6 +880,7 @@ def main(fast: bool = False) -> None:
 
 
 def _cli() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--requests", type=int, default=24)
@@ -920,16 +922,7 @@ def _cli() -> None:
                     help="build + a few ticks only (CI wiring check)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None, help="append record to this file")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation cache dir; run twice "
-                         "and the second run measures steady-state (warm) "
-                         "serving instead of cold-start compiles")
     args = ap.parse_args()
-    if args.compile_cache:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", args.compile_cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     if args.ab:
         rec = run_ab(args.arch, fast=args.fast, dry_run=args.dry_run,
                      overlap=args.overlap, seed=args.seed)
@@ -956,8 +949,6 @@ def _cli() -> None:
         rec = run(args.arch, requests=args.requests, rate=args.rate,
                   capacity=args.capacity, elastic=not args.no_elastic,
                   kv_layout=args.kv_layout, seed=args.seed)
-    if args.compile_cache:
-        rec["compile_cache"] = args.compile_cache
     line = json.dumps(rec)
     print(line)
     if args.out:

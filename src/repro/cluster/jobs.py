@@ -38,8 +38,8 @@ import math
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
+from jax import set_mesh
 
-from ..compat import set_mesh
 from ..core.chunks import Assignment, ChunkStore
 from ..core.cocoa import CoCoASolver
 from ..core.engine import IterationRecord, MicroTaskEmulator, UniTaskEngine

@@ -1,10 +1,11 @@
 """jit'd wrappers: model-layout adapters + TPU/interpret dispatch.
 
-On TPU (`jax.default_backend() == "tpu"``) the Pallas kernels run compiled;
-everywhere else they run in interpret mode (CPU validation).  The model code
-can also bypass kernels entirely (models/attention.py XLA path) — that is
-what the dry-run lowers, since Pallas custom-calls don't lower on the CPU
-SPMD backend.
+`interpret_mode()` is the one place that decides how the Pallas kernels run:
+compiled on a TPU, interpreted on the CPU (validation), and an error on any
+other platform, so a program that misses its chip stops instead of silently
+interpreting.  The model code can also bypass kernels entirely
+(models/attention.py XLA path) — that is what the dry-run lowers, since
+Pallas custom-calls don't lower on the CPU SPMD backend.
 """
 from __future__ import annotations
 
@@ -17,8 +18,16 @@ import jax.numpy as jnp
 from . import chunk_reduce, flash_attention as fa, ref, scd
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted on the default device:
+    True on "cpu", False on "tpu", ValueError on anything else."""
+    platform = jax.devices()[0].platform
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise ValueError(f"Pallas kernels run compiled on 'tpu' or interpreted "
+                     f"on 'cpu'; got platform {platform!r}")
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -34,7 +43,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
     of = fa.flash_attention(qf, kf, vf, causal=causal, window=window,
                             block_q=min(block_q, S), block_k=min(block_k, S),
-                            group_size=G, interpret=_interpret())
+                            group_size=G, interpret=interpret_mode())
     return of.reshape(B, KV, G, S, hd).transpose(0, 3, 1, 2, 4)
 
 
@@ -42,13 +51,13 @@ def scd_local_pass(x, y, alpha, w, mask, lam_n, sigma
                    ) -> Tuple[jax.Array, jax.Array]:
     """CoCoA local SCD pass: x (K,M,F), returns (v_end (K,F), da (K,M))."""
     return scd.scd_pass(x, y, alpha, w, mask, lam_n, sigma,
-                        interpret=_interpret())
+                        interpret=interpret_mode())
 
 
 def merge_updates(updates: jax.Array, weights: jax.Array) -> jax.Array:
     """Weighted uni-task merge: (K, N) x (K,) -> (N,)."""
     return chunk_reduce.weighted_merge(updates, weights,
-                                       interpret=_interpret())
+                                       interpret=interpret_mode())
 
 
 def merge_pytree(deltas, weights):

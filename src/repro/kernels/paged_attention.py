@@ -5,9 +5,9 @@ verification, where the span is [current token, k draft tokens]) attends a
 KV cache scattered across fixed-size physical pages.  The block table and
 per-sequence lengths/query-start positions are SCALAR-PREFETCHED
 (`pltpu.PrefetchScalarGridSpec`) so the k/v BlockSpec index_maps can chase
-them: grid step (b, h, p) DMAs exactly the physical page backing sequence
-b's p-th logical page — the kernel never touches pages the sequence does
-not own.  Pages past a sequence's live length are clamped to the last live
+them: grid step (b, p) DMAs exactly the physical page backing sequence b's
+p-th logical page — the kernel never touches pages the sequence does not
+own.  Pages past a sequence's live length are clamped to the last live
 page in the index_map (a repeated block index, so the pipeline skips the
 re-DMA) and their compute is skipped with `pl.when`: per-sequence work is
 O(live tokens), not O(pool capacity).
@@ -18,14 +18,20 @@ the COMPACT page pool (no head-expansion gather, 1x kv-page traffic).  The
 Q query positions of a span ride along the row dim — row r is query
 position r // G at absolute position q_start[b] + r // G, and each row
 carries its own causal/sliding-window mask, so verifying k drafts costs ONE
-page sweep instead of k+1.  Online-softmax state (acc/m/l per (b, kv))
+page sweep instead of k+1.  Online-softmax state (acc/m/l per kv head)
 lives in VMEM scratch across the page steps, which form the innermost
 (sequential) grid dimension.
 
-Block shapes are (Q*G, hd)/(page_size, hd) — production sizing should pick
-page_size and Q*G*hd at MXU/VPU multiples; correctness is validated on CPU
-in interpret mode against kernels.ref.paged_attention_ref
-(`python -m repro.kernels.paged_attention --selftest`).
+Block shapes: a k/v block is one WHOLE page with all its kv heads,
+(page_size, KV, hd), and the kernel loops over the KV heads inside.  The
+TPU compiler tiles the last two block dims, which must be multiples of
+(8, 128) or span the array: (KV, hd) spans the pool's (N, page_size, KV, hd)
+layout at any head count, page size and dtype, where a one-head block
+(page_size, 1, hd) would not.  q/out blocks are (KV, Q*G, hd) per sequence.
+Correctness is validated on CPU in interpret mode against
+kernels.ref.paged_attention_ref
+(`python -m repro.kernels.paged_attention --selftest`), and the compiled
+kernel is compile-tested for a described TPU v5e (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -48,8 +54,8 @@ def _paged_kernel(table_ref, len_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, sm_scale: float, page_size: int,
                   window: int, q_span: int):
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    QG = q_ref.shape[2]
+    p = pl.program_id(1)
+    KV, QG = q_ref.shape[1], q_ref.shape[2]
     G = QG // q_span
 
     @pl.when(p == 0)
@@ -57,17 +63,13 @@ def _paged_kernel(table_ref, len_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])  # length-0 rows stay 0
+        o_ref[0] = jnp.zeros_like(o_ref[0])  # length-0 rows stay 0
 
     length = len_ref[b]
     n_live = _live_pages(length, page_size)
 
     @pl.when(p < n_live)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)        # (Q*G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (page_size, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale
         k_pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (QG, page_size), 1)
         # row r is query position r // G at absolute position q_start + r//G
@@ -76,21 +78,25 @@ def _paged_kernel(table_ref, len_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
         ok = (k_pos <= q_abs) & (k_pos < length)  # causal + live tail
         if window:  # sliding window from each query's own position
             ok &= (q_abs - k_pos) < window
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        probs = jnp.exp(s - m_cur[:, None])
-        alpha = jnp.exp(m_prev - m_cur)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(probs, v)
-        m_ref[...] = m_cur
-        l_ref[...] = l_prev * alpha + jnp.sum(probs, axis=1)
+        for h in range(KV):  # static: one page DMA serves every kv head
+            q = q_ref[0, h].astype(jnp.float32)        # (Q*G, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page_size, hd)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev, l_prev = m_ref[h], l_ref[h]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            probs = jnp.exp(s - m_cur[:, None])
+            alpha = jnp.exp(m_prev - m_cur)
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + jax.lax.dot(probs, v)
+            m_ref[h] = m_cur
+            l_ref[h] = l_prev * alpha + jnp.sum(probs, axis=1)
 
     @pl.when((p == n_live - 1) & (length > 0))
     def _done():
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe[:, :, None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -121,27 +127,27 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if q_start is None:
         q_start = lengths - q_span
 
-    def kv_map(b, h, p, table, lens, qstart):
+    def kv_map(b, p, table, lens, qstart):
         n_live = _live_pages(lens[b], page_size)
         pc = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        return (jnp.maximum(table[b, pc], 0), 0, h, 0)
+        return (jnp.maximum(table[b, pc], 0), 0, 0, 0)
 
-    def q_map(b, h, p, table, lens, qstart):
-        return (b, h, 0, 0)
+    def q_map(b, p, table, lens, qstart):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KV, P),
+        grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, 1, QG, hd), q_map),
-            pl.BlockSpec((1, page_size, 1, hd), kv_map),
-            pl.BlockSpec((1, page_size, 1, hd), kv_map),
+            pl.BlockSpec((1, KV, QG, hd), q_map),
+            pl.BlockSpec((1, page_size, KV, hd), kv_map),
+            pl.BlockSpec((1, page_size, KV, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, QG, hd), q_map),
+        out_specs=pl.BlockSpec((1, KV, QG, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((QG, hd), jnp.float32),
-            pltpu.VMEM((QG,), jnp.float32),
-            pltpu.VMEM((QG,), jnp.float32),
+            pltpu.VMEM((KV, QG, hd), jnp.float32),
+            pltpu.VMEM((KV, QG), jnp.float32),
+            pltpu.VMEM((KV, QG), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, sm_scale=sm_scale,

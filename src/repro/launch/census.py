@@ -14,8 +14,8 @@ import re  # noqa: E402
 from collections import Counter  # noqa: E402
 
 import jax  # noqa: E402
+from jax import set_mesh  # noqa: E402
 
-from ..compat import set_mesh  # noqa: E402
 from ..configs import INPUT_SHAPES, TrainConfig, get_config  # noqa: E402
 from ..sharding import AxisRules  # noqa: E402
 from . import hlo_cost, steps  # noqa: E402

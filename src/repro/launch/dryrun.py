@@ -18,8 +18,8 @@ import sys  # noqa: E402
 import time  # noqa: E402
 
 import jax  # noqa: E402
+from jax import set_mesh  # noqa: E402
 
-from ..compat import set_mesh  # noqa: E402
 from ..configs import INPUT_SHAPES, TrainConfig, get_config, list_archs  # noqa: E402
 from ..models import model as M  # noqa: E402
 from ..models import transformer as tfm  # noqa: E402

@@ -14,23 +14,18 @@ device_count=8 to see real resharding across 8 'nodes').
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
-import numpy as np
+from jax import set_mesh
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import AxisType, mesh_from_devices, set_mesh
 from ..configs.base import ModelConfig, TrainConfig
 from ..models import model as M
 from ..optim import init_opt_state
 from ..sharding import AxisRules
 from . import steps
-
-
-def data_mesh(devices: Sequence) -> Mesh:
-    return mesh_from_devices(devices, ("data",),
-                             axis_types=(AxisType.Auto,))
+from .mesh import data_mesh
 
 
 class ElasticTrainer:

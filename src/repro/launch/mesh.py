@@ -7,19 +7,18 @@ device count.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-from ..compat import auto_axes, make_mesh
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """TPU v5e production mesh: 16x16 per pod; 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
@@ -28,9 +27,15 @@ def make_host_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
     if data is None:
         data = n // model
     if model > 1:
-        return make_mesh((data, model), ("data", "model"),
-                         axis_types=auto_axes(2))
-    return make_mesh((data,), ("data",), axis_types=auto_axes(1))
+        return jax.make_mesh((data, model), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((data,), ("data",), axis_types=(AxisType.Auto,))
+
+
+def data_mesh(devices: Sequence) -> Mesh:
+    """1-D data-parallel mesh over an explicit device subset (elastic
+    resize: the first k devices)."""
+    return Mesh(np.asarray(devices), ("data",), axis_types=(AxisType.Auto,))
 
 
 def mesh_chips(mesh: Mesh) -> int:

@@ -24,6 +24,7 @@ from ..obs import Tracer, dominant_host_phase, format_attribution, \
 from ..serve import (CircuitBreaker, DisaggEngine, FaultInjector,
                      QueueSplitPolicy, ServeEngine, parse_chaos,
                      poisson_arrivals, synthetic_requests)
+from .cache import init_compile_cache
 from .train import scale_config
 
 
@@ -81,10 +82,11 @@ def serve(arch: str, *, smoke: bool = True, scale: str = "tiny",
           tenant_rate: Optional[float] = None, queue_cap: Optional[int] = None,
           brownout: str = "off",
           seed: int = 0, trace_out: Optional[str] = None) -> Dict:
-    """Run an open-loop serving workload; returns the metrics summary.
-    `trace_out` enables tick-phase tracing and writes a Chrome trace-event
-    JSON file (load in Perfetto / chrome://tracing) plus a per-phase
-    host-vs-device attribution in the returned summary."""
+    """Run an open-loop serving workload; returns the metrics summary plus
+    each request's prompt, emitted tokens and final state under
+    "requests".  `trace_out` enables tick-phase tracing and writes a
+    Chrome trace-event JSON file (load in Perfetto / chrome://tracing)
+    plus a per-phase host-vs-device attribution in the returned summary."""
     cfg = get_config(arch)
     cfg = smoke_variant(cfg) if smoke else scale_config(cfg, scale)
     rng = np.random.default_rng(seed)
@@ -135,6 +137,9 @@ def serve(arch: str, *, smoke: bool = True, scale: str = "tiny",
     out = metrics.summarize()
     out["arch"] = arch
     out["capacity"] = capacity
+    out["requests"] = [{"rid": r.rid, "prompt": r.prompt.tolist(),
+                        "generated": list(r.generated),
+                        "state": r.state.value} for r in metrics.requests]
     if injector is not None:
         out["chaos"] = chaos
         out["faults_injected"] = injector.summary()
@@ -153,10 +158,14 @@ def _fmt_ms(v: Optional[float]) -> str:
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--scale", default="tiny", choices=["tiny", "25m", "100m"])
+    ap.add_argument("--scale", default="tiny",
+                    choices=["tiny", "25m", "100m", "full"],
+                    help="full = the registered config at its published "
+                         "widths (TPU)")
     ap.add_argument("--trace", default="poisson", choices=["poisson", "burst"])
     ap.add_argument("--rate", type=float, default=20.0, help="req/s (poisson)")
     ap.add_argument("--requests", type=int, default=16)

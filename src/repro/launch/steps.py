@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import INPUT_SHAPES, ModelConfig, ShapeConfig, TrainConfig
 from ..models import model as M
 from ..optim import optimizers as opt
@@ -204,7 +203,7 @@ def make_lsgd_train_step(cfg: ModelConfig, rules: AxisRules, tc: TrainConfig):
     bspec = P(data_axes if data_axes else None)
 
     def train_step(params, momentum, batch):
-        fn = shard_map(
+        fn = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(P(), P(), bspec, bspec, bspec),
             out_specs=(P(), P(), P()),

@@ -21,8 +21,9 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import set_mesh
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import set_mesh
 from ..configs import TrainConfig, get_config, smoke_variant
 from ..core import (Assignment, ChunkStore, ElasticScalingPolicy,
                     RebalancePolicy, ScaleEvent)
@@ -32,11 +33,16 @@ from ..models import model as M
 from ..optim import init_opt_state
 from ..sharding import AxisRules
 from . import steps
+from .cache import init_compile_cache
 from .mesh import make_host_mesh
 
 
 def scale_config(cfg, scale: str):
-    """Reduced real-training variants (CPU-sized but non-trivial)."""
+    """Reduced real-training variants (CPU-sized but non-trivial); "full"
+    is the registered config unchanged, at its published widths and its
+    own dtype."""
+    if scale == "full":
+        return cfg
     presets = {
         "tiny": dict(num_layers=2, d_model=128, num_heads=4, num_kv_heads=2,
                      head_dim=32, d_ff=256, vocab_size=512),
@@ -104,9 +110,20 @@ def train(arch: str, *, scale: Optional[str] = None, smoke: bool = False,
         node_pst = (lambda w, f=float(factor), c=int(count):
                     f if w < c else 1.0)
 
-    params = M.init_params(cfg, jax.random.key(seed))
+    # state starts where the step leaves it (param sharding, optimizer
+    # moments alike), so step 1 reuses step 0's executable; the step
+    # donates it, so the old state's buffers hold the new one
+    p_shard = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                           M.param_specs(cfg, rules),
+                           is_leaf=lambda x: isinstance(x, P))
+    params = jax.device_put(M.init_params(cfg, jax.random.key(seed)),
+                            p_shard)
     opt_state = init_opt_state(params, optimizer=tc.optimizer)
-    step_fn = jax.jit(steps.make_train_step(cfg, rules, tc))
+    opt_state = jax.device_put(opt_state, type(opt_state)(
+        step=NamedSharding(mesh, P()), mu=p_shard,
+        nu=None if opt_state.nu is None else p_shard))
+    step_fn = jax.jit(steps.make_train_step(cfg, rules, tc),
+                      donate_argnums=(0, 1))
 
     # lightweight engine loop (scheduler phase -> batch -> compiled step)
     sim_time = 0.0
@@ -150,6 +167,7 @@ def train(arch: str, *, scale: Optional[str] = None, smoke: bool = False,
             sim_time += max(task_times.values())
             loss = float(metrics["loss"])
             history.append({"step": it, "loss": loss,
+                            "wall_s": time.time() - t0,
                             "workers": assignment.n_workers,
                             "sim_time": sim_time,
                             "events": list(stats.get("scale_events", []))})
@@ -164,10 +182,14 @@ def train(arch: str, *, scale: Optional[str] = None, smoke: bool = False,
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--scale", default=None, choices=[None, "tiny", "25m", "100m"])
+    ap.add_argument("--scale", default=None,
+                    choices=[None, "tiny", "25m", "100m", "full"],
+                    help="full = the registered config at its published "
+                         "widths (TPU)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)

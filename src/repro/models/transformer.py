@@ -438,6 +438,7 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
         if kind != "grouped":
             raise NotImplementedError(
                 "pallas paged decode needs the grouped head layout")
+        from ..kernels.ops import interpret_mode
         from ..kernels.paged_attention import paged_attention
         KVh, hd = cfg.kv_heads(), cfg.head_dim_()
         # (B, Q, KV*g_pad, hd) -> (B, KV, Q*g_pad, hd): the kernel rides the
@@ -446,7 +447,7 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
               .transpose(0, 2, 1, 3, 4).reshape(B, KVh, Q * g_pad, hd))
         ctx = paged_attention(qg, ck, cv, table, lengths, window=window,
                               q_span=Q, q_start=q_pos[:, 0],
-                              interpret=jax.default_backend() != "tpu")
+                              interpret=interpret_mode())
         _, hmask = attn.head_maps(cfg)
         ctx = (ctx.reshape(B, KVh, Q, g_pad, hd)
                .transpose(0, 2, 1, 3, 4).reshape(B, Q, HP, hd))

@@ -42,7 +42,8 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
-from ..compat import set_mesh
+from jax import set_mesh
+
 from ..configs.base import ModelConfig
 from ..faults import FaultEvent, FaultInjector
 from ..obs import NULL_TRACER, ScopedTracer, Tracer

@@ -59,11 +59,12 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import set_mesh
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import mesh_from_devices, set_mesh
 from ..configs.base import ModelConfig
 from ..faults import FaultEvent, FaultInjector
+from ..launch.mesh import data_mesh
 from ..models import model as M
 from ..obs import MetricsRegistry, NULL_TRACER, SLOTracker, Tracer, meets_slo
 from ..sharding import AxisRules
@@ -648,7 +649,7 @@ class ServeEngine:
             (self._tick, k, len(moved), int(nbytes)))
 
     def _build(self, km: int):
-        mesh = mesh_from_devices(self.devices[:km], ("data",))
+        mesh = data_mesh(self.devices[:km])
         rules = AxisRules(mesh)
         cfg = self.cfg
 

@@ -6,8 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import TrainConfig, get_config, list_archs, smoke_variant
 from repro.launch.mesh import make_host_mesh
 from repro.launch import steps

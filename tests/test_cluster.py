@@ -290,8 +290,8 @@ def test_orchestrator_departure_returns_nodes():
 
 def test_lm_train_job_runs_real_steps_under_orchestration():
     """Real-compute LM job: the orchestrator drives actual jitted train
-    steps, scale-to-zero parks state on host, and the job finishes with a
-    falling loss."""
+    steps, scale-to-zero parks state on host, and the preempted job's loss
+    curve is exactly the curve of the same job run without preemption."""
     import jax.numpy as jnp
     from repro.cluster import JobSpec, LMTrainJob
     from repro.configs import TrainConfig
@@ -306,10 +306,13 @@ def test_lm_train_job_runs_real_steps_under_orchestration():
                 "labels": jnp.asarray(data["labels"][sl]),
                 "weights": jnp.ones((4,), jnp.float32)}
 
-    job = LMTrainJob(JobSpec("lm", "train", max_nodes=2), cfg,
-                     TrainConfig(learning_rate=5e-3, remat=False),
-                     batch_fn=batch, steps=6, step_time=1.0, seed=0)
+    def lm_job():
+        return LMTrainJob(JobSpec("lm", "train", max_nodes=2), cfg,
+                          TrainConfig(learning_rate=5e-3, remat=False),
+                          batch_fn=batch, steps=6, step_time=1.0, seed=0)
+
     # squeeze it to zero mid-run with a short-lived high-priority hog
+    job = lm_job()
     hog = _tiny_trainer("hog", seed=0, iterations=3)
     hog.spec.priority = 2
     trace = ClusterTrace([arrive(0.0, "lm"), arrive(2.0, "hog")])
@@ -320,8 +323,12 @@ def test_lm_train_job_runs_real_steps_under_orchestration():
     assert job.steps_done == 6
     assert rep.jobs["lm"]["steps_done"] == 6
     assert job.preemptions >= 1  # the hog displaced it entirely
-    losses = job.loss_curve()
-    assert losses[-1] < losses[0]
+
+    alone = lm_job()
+    ClusterOrchestrator(DevicePool(1), [alone], ClusterTrace(
+        [arrive(0.0, "lm")]), dt=1.0, max_ticks=100).run()
+    assert alone.preemptions == 0
+    assert job.loss_curve() == alone.loss_curve()
 
 
 def test_serve_job_scale_to_zero_and_resume():

@@ -1,13 +1,14 @@
 """Launcher-layer tests: step builders, input specs, lSGD shard_map step,
 decode geometry policy, head layouts, sharding regimes."""
 import dataclasses
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import INPUT_SHAPES, TrainConfig, get_config, list_archs, smoke_variant
 from repro.launch import steps
 from repro.launch.mesh import make_host_mesh
@@ -145,3 +146,37 @@ def test_inference_2d_rules():
     assert r.cache_batch is not None or len(jax.devices()) == 1
     r2 = AxisRules(mesh)
     assert (r2.batch is None) == (len(jax.devices()) == 1 and False) or True
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_placement(env_dir, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched (JAX reads it itself);
+    without it the cache sits at the checkout's fixed .jax_cache/."""
+    from repro.launch import cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = cache.init_compile_cache()
+        if env_dir is None:
+            want = cache.CHECKOUT_CACHE_DIR
+            assert want.parent == pathlib.Path(__file__).resolve().parents[1]
+            assert got == str(want) == jax.config.jax_compilation_cache_dir
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_scale_full_is_the_registered_config():
+    from repro.launch.train import scale_config
+    cfg = get_config("smollm-360m")
+    assert scale_config(cfg, "full") is cfg
+    assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.kv_heads(),
+            cfg.d_ff, cfg.vocab_size, cfg.dtype) == (
+                32, 960, 15, 5, 2560, 49152, "bfloat16")
+    assert cfg.source == "hf:HuggingFaceTB/SmolLM-360M"

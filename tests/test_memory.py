@@ -4,8 +4,8 @@ eviction may only change bytes moved and pages held, never a single token,
 including across elastic resizes and preempt/park/restore cycles."""
 import numpy as np
 import pytest
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import get_config, smoke_variant
 from repro.core import ElasticScalingPolicy, ScaleEvent
 from repro.serve import (KVMemoryManager, PageAllocator, PageError, Request,

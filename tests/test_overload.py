@@ -8,9 +8,9 @@ and the disagg handoff queue, and the SLO feedback paths into the split
 policy and the fair-share allocator."""
 import numpy as np
 import pytest
+from jax import set_mesh
 
 from repro.cluster import FairShareAllocator, JobDemand
-from repro.compat import set_mesh
 from repro.configs import get_config, smoke_variant
 from repro.faults import FaultInjector, FaultPlan, crash_storm, worker_crash
 from repro.obs import SLOTracker, Tracer, meets_slo, overload_timeline

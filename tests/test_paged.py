@@ -6,8 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import get_config, smoke_variant
 from repro.core import ElasticScalingPolicy, ScaleEvent
 from repro.kernels import ref

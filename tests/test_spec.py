@@ -6,8 +6,8 @@ repetitive vs random workloads, rollback invariants after partial rejection
 satellite (fewer dispatches, identical tokens)."""
 import numpy as np
 import pytest
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import get_config, smoke_variant
 from repro.core import ElasticScalingPolicy, ScaleEvent
 from repro.serve import (DraftModelDrafter, NgramDrafter, Request,
@@ -193,20 +193,87 @@ def test_spec_with_chunked_prefill(cfg):
 # ---------------------------------------------------------------------------
 
 
+def _next_token_map(cfg, params):
+    """g(t): the greedy next token of a window-1 model, whose every
+    position attends only itself, so its next token is a function of the
+    current token alone."""
+    import jax.numpy as jnp
+    from repro.models import model as M
+    toks = jnp.arange(cfg.vocab_size, dtype=jnp.int32)[:, None]
+    logits, _ = M.forward(cfg, params, toks, rules=None, remat=False)
+    return np.asarray(jnp.argmax(logits[:, -1], -1))
+
+
+def _cycle(nxt):
+    """A cycle of the map t -> nxt[t] (distinct tokens, nxt[c[-1]] = c[0])."""
+    seen, t = {}, 0
+    while t not in seen:
+        seen[t] = len(seen)
+        t = int(nxt[t])
+    order = sorted(seen, key=seen.get)
+    return order[seen[t]:]
+
+
+def _misleading(nxt, n, seed, prompt_len=(12, 19), max_new=(4, 7)):
+    """Random prompts whose own history misleads the drafter: the model's
+    first token x = nxt[last] occurs once earlier in the prompt, followed
+    by a token other than nxt[x], so the first draft is rejected."""
+    V = len(nxt)
+    rng = np.random.default_rng(seed)
+    reqs = []
+    while len(reqs) < n:
+        plen = int(rng.integers(*prompt_len))
+        prompt = rng.integers(0, V, size=plen)
+        last = int(prompt[-1])
+        x = int(nxt[last])
+        j = int(rng.integers(0, plen - 2))
+        prompt[j] = x
+        rest = np.delete(np.arange(plen), [j, plen - 1])
+        if (x == last or int(prompt[j + 1]) == int(nxt[x])
+                or np.isin(prompt[rest], [x, last]).any()):
+            continue
+        reqs.append(Request(rid=len(reqs), prompt=prompt.astype(np.int32),
+                            max_new_tokens=int(rng.integers(*max_new))))
+    return reqs
+
+
 def test_acceptance_repetitive_beats_random(cfg):
-    """Prompt-lookup drafting locks onto repetitive prompts; random-token
-    prompts only accept once the model's own stream starts looping, so the
-    repetitive workload must accept strictly more (and well above zero)."""
+    """Prompt-lookup drafting on a model whose next token depends on the
+    current token alone (attention window 1): prompts that tile a cycle of
+    that map are continued by the model exactly as the drafter proposes,
+    so every draft is accepted; prompts whose history misleads the drafter
+    reject their first draft.  Repetitive prompts therefore accept strictly
+    more, by construction."""
+    import dataclasses
+
+    import jax
+    from repro.models import model as M
+    cfg1 = dataclasses.replace(cfg, sliding_window=1)
+    params = M.init_params(cfg1, jax.random.key(0))
+    nxt = _next_token_map(cfg1, params)
+    cyc = np.asarray(_cycle(nxt), np.int32)
+    rng = np.random.default_rng(1)
+    rep = []
+    for i in range(6):
+        plen = int(rng.integers(12, 20))
+        tiled = np.tile(np.roll(cyc, -i), -(-plen // len(cyc)) + 1)[:plen]
+        rep.append(Request(rid=i, prompt=tiled,
+                           max_new_tokens=int(rng.integers(4, 7))))
     accs = {}
-    for name, reqs in (("rep", _repetitive(cfg, seed=1)),
-                       ("rand", _burst(cfg, 6, seed=1, prompt=(12, 19),
-                                       max_new=(4, 7)))):
-        eng = ServeEngine(cfg, capacity=8, cache_len=64, prefill_bucket=16,
-                          n_workers=1, seed=0, kv_layout="paged",
-                          spec="ngram", spec_k=4, debug_checks=True)
-        accs[name] = eng.run(reqs).summarize()["spec_acceptance_rate"]
+    for name, reqs in (("rep", rep), ("rand", _misleading(nxt, 6, seed=1))):
+        eng = ServeEngine(cfg1, capacity=8, cache_len=64, prefill_bucket=16,
+                          n_workers=1, seed=0, params=params,
+                          kv_layout="paged", spec="ngram", spec_k=4,
+                          debug_checks=True)
+        m = eng.run(reqs)
+        for r in m.requests:  # the window-1 model follows its map exactly
+            ctx = list(r.prompt) + r.generated
+            assert all(int(nxt[a]) == b for a, b in
+                       zip(ctx[r.prompt_len - 1:], r.generated)), r.rid
+        accs[name] = m.summarize()["spec_acceptance_rate"]
+    assert accs["rep"] == 1.0, accs
+    assert accs["rand"] < 1.0, accs
     assert accs["rep"] > accs["rand"], accs
-    assert accs["rep"] > 0.5, accs
 
 
 def test_spec_raises_tokens_per_dispatch(cfg):
