@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig, TrainConfig
 from ..models import model as M
+from ..obs import NULL_TRACER, Tracer
 from ..optim import init_opt_state
 from ..sharding import AxisRules
 from . import steps
@@ -35,11 +36,17 @@ class ElasticTrainer:
       optimizer state onto it (device_put — the chunk-transfer analogue for
       model state), and fetch the jit-cached step for that mesh.
     - training state survives every resize; compiled steps are cached per k.
+    - `tracer`: `train_step` runs under spans ``train.put`` (the batch
+      transfer), ``train.dispatch`` (the jitted step call) and
+      ``train.sync`` (``cat="device"``: the host waits for the step's
+      metrics).  Off (`NULL_TRACER`) by default.
     """
 
-    def __init__(self, cfg: ModelConfig, tc: TrainConfig, *, seed: int = 0):
+    def __init__(self, cfg: ModelConfig, tc: TrainConfig, *, seed: int = 0,
+                 tracer: Optional[Tracer] = None):
         self.cfg = cfg
         self.tc = tc
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.devices = list(jax.devices())
         self.params = M.init_params(cfg, jax.random.key(seed))
         self.opt_state = init_opt_state(self.params, optimizer=tc.optimizer)
@@ -95,8 +102,12 @@ class ElasticTrainer:
             spec = P("data") if v.shape[0] % self.k == 0 else P()
             return NamedSharding(self.mesh, spec)
 
-        batch = {k: jax.device_put(v, shard_for(v)) for k, v in batch.items()}
-        with set_mesh(self.mesh):
+        trc = self.tracer
+        with trc.span("train.put"):
+            batch = {k: jax.device_put(v, shard_for(v))
+                     for k, v in batch.items()}
+        with trc.span("train.dispatch"), set_mesh(self.mesh):
             self.params, self.opt_state, metrics = self.step(
                 self.params, self.opt_state, batch)
-        return {k: float(v) for k, v in metrics.items()}
+        with trc.span("train.sync", cat="device"):
+            return {k: float(v) for k, v in metrics.items()}

@@ -3,7 +3,9 @@
 - `trace`   — `Tracer` with nestable exception-safe spans, explicit
               device-sync attribution (``cat="device"`` spans around
               ``block_until_ready``), instant events, Chrome trace-event
-              JSON export (Perfetto-loadable), and a disabled fast path
+              JSON export (Perfetto-loadable), an optional bridge that
+              puts each span into the jax profiler's trace
+              (``profiler=True``), and a disabled fast path
               that is one attribute check (`NULL_TRACER` is the shared
               default everywhere, so un-traced runs stay bit-identical
               and unslowed)
@@ -17,8 +19,9 @@
               control signal for overload brownouts and split/allocator
               feedback; traced `slo.miss` instants
 
-The serving engine, cluster orchestrator, and benchmarks all thread a
-`Tracer` through; nothing here imports jax or numpy.
+The serving engine, elastic trainer, cluster orchestrator, and benchmarks
+all thread a `Tracer` through; nothing here imports jax or numpy, except
+the profiler bridge, which imports jax when a tracer turns it on.
 """
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry, percentile
 from .report import (dominant_host_phase, format_attribution,

@@ -25,7 +25,14 @@ Spans are exception-safe: a span whose body raises is still recorded (with
 `MetricsRegistry` via `count`/`gauge`/`observe` helpers that no-op when
 disabled, so callers never branch on ``tracer.enabled`` themselves.
 
-Stdlib-only; single-threaded by design (one tracer per engine/orchestrator
+``Tracer(profiler=True)`` also holds a ``jax.profiler.TraceAnnotation`` of
+the span's name (``<scope>.<name>`` under a `ScopedTracer`) for each span's
+duration, so the spans land on the profiler's host plane, on the device
+trace's clock.  Only the name goes there: args stay in memory.  Instants
+and `complete()` spans are recorded after the fact and stay tracer-only.
+
+Stdlib-only (jax is imported only when the profiler bridge is on);
+single-threaded by design (one tracer per engine/orchestrator
 tick loop).
 
 CLI: ``python -m repro.obs.trace --validate trace.json --require a,b``
@@ -71,20 +78,32 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation`, imported on first use so that
+    `repro.obs` stays importable without jax."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
 class _Span:
     __slots__ = ("_tracer", "_name", "_cat", "_track", "_args", "_t0",
-                 "_depth")
+                 "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, track: str,
-                 args: Dict[str, Any]):
+                 args: Dict[str, Any], label: Optional[str] = None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._track = track
         self._args = args
+        # the profiler annotation, opened on entry (None: profiler off)
+        self._ann = (tracer._annotate(label) if label is not None
+                     else None)
 
     def __enter__(self) -> "_Span":
         tr = self._tracer
+        if self._ann is not None:
+            self._ann.__enter__()
         self._depth = tr._depth
         tr._depth += 1
         self._t0 = tr._clock()
@@ -94,21 +113,30 @@ class _Span:
         tr = self._tracer
         t1 = tr._clock()
         tr._depth -= 1
-        tr.events.append(TraceEvent(
-            name=self._name, ph="X", track=self._track, cat=self._cat,
-            ts=self._t0 - tr._epoch, dur=t1 - self._t0, depth=self._depth,
-            args=self._args, error=etype is not None))
+        try:
+            tr.events.append(TraceEvent(
+                name=self._name, ph="X", track=self._track, cat=self._cat,
+                ts=self._t0 - tr._epoch, dur=t1 - self._t0,
+                depth=self._depth, args=self._args,
+                error=etype is not None))
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(etype, evalue, tb)
         return False  # never swallow the exception
 
 
 class Tracer:
     """Span recorder + metrics front.  ``enabled=False`` (the default for
-    `NULL_TRACER`) turns every call into a near-free no-op."""
+    `NULL_TRACER`) turns every call into a near-free no-op.
+    ``profiler=True`` also puts every span into the jax profiler's trace
+    (see the module docstring)."""
 
     def __init__(self, enabled: bool = True, *, name: str = "repro",
                  registry: Optional[MetricsRegistry] = None,
-                 clock=time.perf_counter):
+                 clock=time.perf_counter, profiler: bool = False):
         self.enabled = bool(enabled)
+        # the profiler bridge: TraceAnnotation, or None when off
+        self._annotate = _trace_annotation() if enabled and profiler else None
         self.name = name
         self.registry = registry if registry is not None else MetricsRegistry()
         self._clock = clock
@@ -130,7 +158,7 @@ class Tracer:
             return NOOP_SPAN
         return _Span(self, name, cat,
                      track if track is not None else self.default_track(name),
-                     args)
+                     args, name if self._annotate is not None else None)
 
     def instant(self, name: str, track: Optional[str] = None,
                 **args) -> None:
@@ -246,6 +274,7 @@ class ScopedTracer(Tracer):
         self.parent = parent
         self.scope = scope
         self.enabled = parent.enabled
+        self._annotate = parent._annotate
         self.name = parent.name
         self.registry = parent.registry
         self._clock = parent._clock
@@ -272,7 +301,9 @@ class ScopedTracer(Tracer):
         if not self.enabled:
             return NOOP_SPAN
         base = track if track is not None else self.default_track(name)
-        return _Span(self, name, cat, f"{self.scope}.{base}", args)
+        return _Span(self, name, cat, f"{self.scope}.{base}", args,
+                     f"{self.scope}.{name}" if self._annotate is not None
+                     else None)
 
     def instant(self, name: str, track: Optional[str] = None,
                 **args) -> None:

@@ -111,6 +111,7 @@ class TickRecord:
     shed: int = 0  # requests expired this tick (retry budget / deadline)
     brownout_level: int = 0  # degradation-ladder level this tick (0 = full)
     meta_transfers: int = 0  # packed host->device metadata transfers
+    prefill_wait_s: float = 0.0  # host blocked on prefill results (seconds)
 
 
 @dataclasses.dataclass
@@ -316,18 +317,17 @@ def _lru_get(cache: Dict, key, build: Callable[[], Any], cap: int,
              tracer: Optional[Tracer] = None, label: str = ""):
     """Move-to-end LRU over an insertion-ordered dict.  A miss is a jit
     retrace/compile: when a tracer is attached it gets an instant
-    ``jit.miss`` event and the build runs under a ``jit.build`` span, so
-    cache churn (e.g. resize storms evicting executables) is visible in
-    the trace instead of showing up as a mysteriously slow phase."""
+    ``jit.miss`` event and a ``serve.jit_misses`` count, so cache churn
+    (e.g. resize storms evicting executables) is visible in the trace.
+    The build only wraps the function; the compile itself runs at the
+    first call, inside the dispatch span that makes it."""
     if key in cache:
         cache[key] = cache.pop(key)
-    elif tracer is not None and tracer.enabled:
-        tracer.instant("jit.miss", track="jit", label=label, key=str(key))
-        tracer.count("serve.jit_misses")
-        with tracer.span("jit.build", track="jit", label=label,
-                         key=str(key)):
-            cache[key] = build()
     else:
+        if tracer is not None and tracer.enabled:
+            tracer.instant("jit.miss", track="jit", label=label,
+                           key=str(key))
+            tracer.count("serve.jit_misses")
         cache[key] = build()
     while len(cache) > cap:
         cache.pop(next(iter(cache)))
@@ -575,6 +575,7 @@ class ServeEngine:
         self.overlap_hook: Optional[Callable[[], Any]] = None
         self._meta = _MetaPacker()
         self._tick_meta = 0  # packed metadata transfers this tick
+        self._tick_prefill_wait = 0.0  # seconds blocked on prefill results
         # block-table image staged in the previous tick's overlap window;
         # consumed (or discarded on any page/membership change) at bind
         self._plan: Optional[Dict[str, Any]] = None
@@ -1353,9 +1354,7 @@ class ServeEngine:
             # settle at prefill's OWN sync point (first token AND the
             # insert scatter), so prefill device time lands on the prefill
             # track instead of inside the next decode's device_wait
-            with trc.span("prefill.device_wait", cat="device",
-                          track="prefill"):
-                jax.block_until_ready((nxt, self.blocks))
+            self._prefill_wait((nxt, self.blocks))
             nxt = np.asarray(nxt)
             now = self._now()
             for i, r in enumerate(group):
@@ -1423,9 +1422,7 @@ class ServeEngine:
                         done_group.append((i, req))
                         continue
                     if nxt_np is None:
-                        with self.tracer.span("prefill.device_wait",
-                                              cat="device", track="prefill"):
-                            jax.block_until_ready((nxt, self.blocks))
+                        self._prefill_wait((nxt, self.blocks))
                         nxt_np = np.asarray(nxt)
                     self._start_decoding(req, int(nxt_np[i]), self._now())
                 else:
@@ -1700,14 +1697,22 @@ class ServeEngine:
         dispatch order (same order the synchronous path runs them)."""
         if not pending:
             return
-        with self.tracer.span("prefill.device_wait", cat="device",
-                              track="prefill"):
-            jax.block_until_ready(([h for h, _ in pending], self.blocks))
+        self._prefill_wait(([h for h, _ in pending], self.blocks))
         now = self._now()
         for handle, group in pending:
             nxt_np = np.asarray(handle)
             for i, req in group:
                 self._start_decoding(req, int(nxt_np[i]), now)
+
+    def _prefill_wait(self, handles) -> None:
+        """Block on prefill results: the ``prefill.device_wait`` span, and
+        the time blocked added to this tick's `TickRecord.prefill_wait_s`
+        (with overlap on, every decoding slot waits here too)."""
+        t0 = time.perf_counter()
+        with self.tracer.span("prefill.device_wait", cat="device",
+                              track="prefill"):
+            jax.block_until_ready(handles)
+        self._tick_prefill_wait += time.perf_counter() - t0
 
     def _prep_next_plan(self) -> None:
         """Stage next tick's decode block table inside the overlap window.
@@ -1813,9 +1818,7 @@ class ServeEngine:
             # prefill-only tick with nothing deferred (e.g. all chunked
             # admissions, or restore-only): settle the outstanding KV
             # scatters so wall-clock metrics charge the issuing tick
-            with trc.span("prefill.device_wait", cat="device",
-                          track="prefill"):
-                jax.block_until_ready(self.blocks)
+            self._prefill_wait(self.blocks)
         return (admission_bytes, n_chunks, n_chunk_dispatch, emitted,
                 t_step, drafted, accepted, draft_disp)
 
@@ -1829,6 +1832,7 @@ class ServeEngine:
         trc = self.tracer
         tick_t0 = time.perf_counter() if trc.enabled else 0.0
         self._tick_meta = 0  # packed host->device transfers this tick
+        self._tick_prefill_wait = 0.0  # seconds blocked on prefill results
 
         # ---- fault phase: injected faults land BEFORE the scheduler so a
         # crash on the same tick as a scale event has a fixed, replayable
@@ -1950,9 +1954,7 @@ class ServeEngine:
                     # prefill-only tick: settle the outstanding KV
                     # scatters so wall-clock metrics charge the work to
                     # the tick that issued it
-                    with trc.span("prefill.device_wait", cat="device",
-                                  track="prefill"):
-                        jax.block_until_ready(self.blocks)
+                    self._prefill_wait(self.blocks)
 
         if self.debug_checks:
             # page-leak guard: every live slot must hold EXACTLY the pages
@@ -2018,6 +2020,7 @@ class ServeEngine:
                          brownout_level=(self.ladder.level
                                          if self.ladder is not None else 0),
                          meta_transfers=self._tick_meta,
+                         prefill_wait_s=self._tick_prefill_wait,
                          **kv)
         self._tick_faults = {"crashes": 0, "retries": 0, "shed": 0}
         self.metrics.ticks.append(rec)

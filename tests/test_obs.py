@@ -1,15 +1,17 @@
-"""Observability tests: span mechanics, Chrome export validity, the
-disabled-tracer zero-cost/bit-identical contract, percentile math vs numpy,
-attribution, registry re-backing, and the cluster trace stream."""
+"""Observability tests: span mechanics, the profiler bridge, Chrome export
+validity, the disabled-tracer zero-cost/bit-identical contract, percentile
+math vs numpy, attribution, registry re-backing, and the cluster trace
+stream."""
+import glob
 import json
 
 import numpy as np
 import pytest
 
 from repro.configs import get_config, smoke_variant
-from repro.obs import (NOOP_SPAN, NULL_TRACER, MetricsRegistry, Tracer,
-                       dominant_host_phase, percentile, phase_attribution,
-                       validate_chrome_trace)
+from repro.obs import (NOOP_SPAN, NULL_TRACER, MetricsRegistry,
+                       ScopedTracer, Tracer, dominant_host_phase, percentile,
+                       phase_attribution, validate_chrome_trace)
 from repro.serve import ServeEngine, poisson_arrivals, synthetic_requests
 
 
@@ -106,6 +108,81 @@ def test_disabled_overhead_guard():
             pass
     assert time.perf_counter() - t0 < 2.0
     assert trc.events == []
+
+
+# ---------------------------------------------------------------------------
+# Profiler bridge: spans on the jax profiler's host plane
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def host_events(tmp_path_factory):
+    """One CPU profiler trace around every case the bridge tests read:
+    {name: [(start_ns, end_ns)]} of the `/host:CPU` plane's events, and the
+    in-memory events of the bridged tracer."""
+    import jax
+    from jax.profiler import ProfileData
+
+    d = tmp_path_factory.mktemp("prof")
+    on = Tracer(profiler=True)
+    pool = ScopedTracer(on, "prefill")
+    jax.profiler.start_trace(str(d))
+    try:
+        with on.span("bridge.tick"):
+            with on.span("bridge.dispatch", n=3):
+                pass
+            with pool.span("bridge.pooled"):
+                pass
+        with pytest.raises(ValueError):
+            with on.span("bridge.raises"):
+                raise ValueError("boom")
+        with on.span("bridge.after"):
+            pass
+        with Tracer(enabled=False, profiler=True).span("bridge.disabled"):
+            pass
+        with Tracer(profiler=False).span("bridge.unbridged"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(d / "**" / "*.xplane.pb"), recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return out, on
+
+
+def test_profiler_bridge_spans_land_nested_on_host_plane(host_events):
+    ev, on = host_events
+    (t0, t1), = ev["bridge.tick"]
+    (d0, d1), = ev["bridge.dispatch"]
+    (p0, p1), = ev["prefill.bridge.pooled"]  # a scope keeps its pool apart
+    assert "bridge.pooled" not in ev
+    assert t0 <= d0 <= d1 <= p0 <= p1 <= t1
+    # in-memory events and their args are unchanged by the bridge
+    assert [e.name for e in on.spans()][:3] == [
+        "bridge.dispatch", "bridge.pooled", "bridge.tick"]
+    assert on.spans("bridge.dispatch")[0].args == {"n": 3}
+    assert on.spans("bridge.pooled")[0].track == "prefill.bridge"
+    assert not any(n.startswith("bridge.dispatch") and n != "bridge.dispatch"
+                   for n in ev)  # the annotation carries the name only
+
+
+def test_profiler_annotation_closes_when_body_raises(host_events):
+    ev, on = host_events
+    (r0, r1), = ev["bridge.raises"]  # recorded: its annotation was exited
+    (a0, a1), = ev["bridge.after"]
+    assert r1 <= a0  # the next span is not nested in the failed one
+    assert on.spans("bridge.raises")[0].error and on._depth == 0
+
+
+def test_disabled_or_unbridged_tracer_adds_no_host_event(host_events):
+    ev, _ = host_events
+    assert "bridge.disabled" not in ev and "bridge.unbridged" not in ev
+    assert Tracer(enabled=False, profiler=True).span("x") is NOOP_SPAN
 
 
 # ---------------------------------------------------------------------------
@@ -226,9 +303,31 @@ def test_traced_engine_covers_phases(cfg):
     reg = trc.registry
     assert reg.counter("serve.ticks").value == len(eng.metrics.ticks)
     assert reg.histogram("serve.tick_s").count == len(eng.metrics.ticks)
-    # chunked admissions + per-k jit caches showed up
+    # chunked admissions + per-k jit caches showed up; a miss is an
+    # instant, not a span (the compile runs inside the dispatch span)
     assert reg.counter("serve.jit_misses").value > 0
+    assert any(e.name == "jit.miss" for e in trc.events)
+    assert not trc.spans("jit.build")
     assert trc.spans("prefill.chunk")
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+def test_tick_records_time_blocked_on_prefills(cfg, traced):
+    """`TickRecord.prefill_wait_s` is the tick's time in
+    `prefill.device_wait`, kept whether or not a tracer is attached: the
+    overlapped engine with whole-prompt prefill blocks there on exactly the
+    ticks that admitted."""
+    trc = Tracer() if traced else None
+    _, eng = _streams(cfg, tracer=trc, kv_layout="paged", page_size=8,
+                      chunked_prefill=False, overlap=True)
+    ticks = eng.metrics.ticks
+    assert [t.prefill_wait_s > 0 for t in ticks] == [t.admitted > 0
+                                                     for t in ticks]
+    if traced:
+        waits = trc.spans("prefill.device_wait")
+        assert len(waits) == sum(1 for t in ticks if t.admitted)
+        assert sum(t.prefill_wait_s for t in ticks) >= sum(
+            w.dur for w in waits)
 
 
 def test_serve_metrics_registry_backing(cfg):
