@@ -1,33 +1,49 @@
 """Paged-attention decode Pallas TPU kernel (block-table gather, O(live)).
 
 One QUERY SPAN per sequence (Q=1 plain decode; Q=k+1 speculative
-verification, where the span is [current token, k draft tokens]) attends a
-KV cache scattered across fixed-size physical pages.  The block table and
-per-sequence lengths/query-start positions are SCALAR-PREFETCHED
-(`pltpu.PrefetchScalarGridSpec`) so the k/v BlockSpec index_maps can chase
-them: grid step (b, p) DMAs exactly the physical page backing sequence b's
-p-th logical page — the kernel never touches pages the sequence does not
-own.  Pages past a sequence's live length are clamped to the last live
-page in the index_map (a repeated block index, so the pipeline skips the
-re-DMA) and their compute is skipped with `pl.when`: per-sequence work is
-O(live tokens), not O(pool capacity).
+verification, where the span is [current token, k draft tokens]; Q=chunk
+for a chunked-prefill chunk) attends a KV cache scattered across
+fixed-size physical pages of one pool, `(N, page_size, KV, hd)`, which
+stays in HBM.
 
-Head layout is grouped-GQA like kernels/flash_attention.py: q is
-(B, KV, Q*G, hd) with the G query heads of kv head `kv` contracting against
-the COMPACT page pool (no head-expansion gather, 1x kv-page traffic).  The
-Q query positions of a span ride along the row dim — row r is query
-position r // G at absolute position q_start[b] + r // G, and each row
-carries its own causal/sliding-window mask, so verifying k drafts costs ONE
-page sweep instead of k+1.  Online-softmax state (acc/m/l per kv head)
-lives in VMEM scratch across the page steps, which form the innermost
-(sequential) grid dimension.
+Blocks of pages.  The grid is (B, KV / H, ceil(P / ppb)): step (b, g, j)
+covers kv heads g*H .. g*H + H - 1 of sequence b against its logical pages
+j*ppb .. j*ppb + ppb - 1.  The pool is passed as ppb K operands and ppb V
+operands (the same arrays; XLA adds no copy), each with a one-page
+BlockSpec `(1, page_size, KV, hd)`, and the Pallas pipeline double-buffers
+every operand: the next block's page DMAs run while the current block
+computes.  Which page operand i fetches at block j is computed once per
+call, outside the kernel, by `_fetch_plan` and SCALAR-PREFETCHED
+(`pltpu.PrefetchScalarGridSpec`) with the lengths and query starts, so an
+index map is one SMEM load; scalar work per page DMA is what bounded the
+page-at-a-time kernel.  Past a sequence's live length an operand repeats
+the page it held at the previous step and the pipeline starts no DMA: a
+block wholly past the live length fetches nothing and skips its compute,
+and the dead tail of a partial last block costs no DMA and is masked.  The
+kernel never reads a page the sequence does not own; per-sequence work is
+O(live tokens), not O(table width).
 
-Block shapes: a k/v block is one WHOLE page with all its kv heads,
-(page_size, KV, hd), and the kernel loops over the KV heads inside.  The
-TPU compiler tiles the last two block dims, which must be multiples of
-(8, 128) or span the array: (KV, hd) spans the pool's (N, page_size, KV, hd)
-layout at any head count, page size and dtype, where a one-head block
-(page_size, 1, hd) would not.  q/out blocks are (KV, Q*G, hd) per sequence.
+Compute per block, all H heads in one contraction.  A page block
+`(page_size, KV, hd)` is read as `(page_size*KV, hd)` rows (token-major,
+head-minor), the pool's own tiled layout, so no head is sliced out of it.
+The H*Q*G query rows score against every row of the block in one
+`(H*Q*G, ppb*page_size*KV)` MXU contraction (q.k in the pool's dtype, f32
+accumulation); a row's scores against another head's keys are masked with
+the causal / sliding-window / live-tail positions, so each query row
+softmaxes over its own head's keys only.  Scores, the online softmax
+(acc/m/l in VMEM scratch across blocks) and P.V stay in float32: for a
+bfloat16 pool, P is split exactly into three bfloat16 terms
+(P = p1 + p2 + p3, 24 significand bits) that share one pass of V through
+the MXU, so every product is exact and the sum accumulates in float32.
+Row r of a step's q is query position (r % (Q*G)) // G of kv head
+g*H + r // (Q*G), at absolute position q_start[b] + (r % (Q*G)) // G, each
+with its own mask, so verifying k drafts costs ONE sweep instead of k+1.
+
+Block sizes.  `_block_sizes` picks (H, ppb) from the shapes alone: every
+kv head and `_BLOCK_TOKENS` tokens a block at decode widths; fewer tokens
+as the score tile grows with Q*G (verify spans, chunked prefill), and
+fewer heads a step only where one page's tile is already too large (long
+chunks); at least one page and at most the table width.
 Correctness is validated on CPU in interpret mode against
 kernels.ref.paged_attention_ref
 (`python -m repro.kernels.paged_attention --selftest`), and the compiled
@@ -44,21 +60,74 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_BLOCK_TOKENS = 512           # tokens a block at decode widths
+_SCORE_TILE = 1 << 17         # float32 elements of one block's score tile
 
 
 def _live_pages(length, page_size: int):
     return (length + page_size - 1) // page_size
 
 
-def _paged_kernel(table_ref, len_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, sm_scale: float, page_size: int,
-                  window: int, q_span: int):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    KV, QG = q_ref.shape[1], q_ref.shape[2]
-    G = QG // q_span
+def _block_sizes(page_size: int, kv_heads: int, rows: int,
+                 n_pages: int) -> tuple[int, int]:
+    """(kv heads, pages) a grid step covers.  Every kv head, unless even one
+    page's score tile (heads * rows, page_size * kv_heads) would pass
+    `_SCORE_TILE` elements (a long chunked-prefill span): then halve the
+    heads.  Pages: `_BLOCK_TOKENS` tokens, fewer where the tile would pass
+    `_SCORE_TILE`; at least 1 and at most the table width `n_pages`."""
+    heads = kv_heads
+    while heads % 2 == 0 and heads * rows * page_size * kv_heads > _SCORE_TILE:
+        heads //= 2
+    tokens = min(_BLOCK_TOKENS, _SCORE_TILE // (heads * rows * kv_heads))
+    return heads, max(1, min(tokens // page_size, n_pages))
 
-    @pl.when(p == 0)
+
+def _fetch_plan(block_table, lengths, page_size: int, ppb: int):
+    """(B, ceil(P / ppb) * ppb) physical page that K/V operand i fetches at
+    block j, in column j*ppb + i: the table's page while it is live; past
+    the live length the operand's own last live page again (a repeated
+    block index, so the pipeline starts no DMA); an operand with no live
+    page takes the sequence's last page, or the null page 0."""
+    B, P = block_table.shape
+    n_live = _live_pages(lengths, page_size)[:, None]
+    col = jnp.arange(pl.cdiv(P, ppb) * ppb, dtype=jnp.int32)[None]
+    i = col % ppb
+    last = i + (n_live - 1 - i) // ppb * ppb
+    logical = jnp.where(n_live > i, jnp.minimum(col, last),
+                        jnp.maximum(n_live - 1, 0))
+    page = jnp.take_along_axis(block_table.astype(jnp.int32), logical, axis=1)
+    return jnp.maximum(page, 0)
+
+
+def _exact_parts(p, dtype):
+    """float32 `p` as terms of `dtype` whose float32 sum is `p`: p itself
+    for a float32 pool; three bfloat16 terms (8 significand bits each)
+    for a narrower one."""
+    if dtype == jnp.float32:
+        return [p]
+    parts = []
+    for _ in range(3):
+        part = p.astype(dtype)
+        parts.append(part)
+        p = p - part.astype(jnp.float32)
+    return parts
+
+
+def _paged_kernel(pages_ref, len_ref, qstart_ref, q_ref, *refs,
+                  sm_scale: float, page_size: int, ppb: int, window: int,
+                  q_span: int):
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * ppb:]
+    b = pl.program_id(0)
+    g = pl.program_id(1)          # group of kv heads
+    j = pl.program_id(2)          # block of pages
+    H, QG, hd = q_ref.shape[1:]
+    KV = k_refs[0].shape[2]
+    G = QG // q_span
+    T = ppb * page_size           # tokens a block
+    R, C = H * QG, T * KV         # score tile: (head, query row) x (token, kv)
+
+    @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -66,37 +135,45 @@ def _paged_kernel(table_ref, len_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = jnp.zeros_like(o_ref[0])  # length-0 rows stay 0
 
     length = len_ref[b]
-    n_live = _live_pages(length, page_size)
+    n_blocks = (length + T - 1) // T
 
-    @pl.when(p < n_live)
+    @pl.when(j < n_blocks)
     def _accumulate():
-        k_pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (QG, page_size), 1)
-        # row r is query position r // G at absolute position q_start + r//G
-        q_abs = qstart_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (QG, page_size), 0) // G
-        ok = (k_pos <= q_abs) & (k_pos < length)  # causal + live tail
+        dt = k_refs[0].dtype
+        k = jnp.concatenate([r[0].reshape(page_size * KV, hd)
+                             for r in k_refs], axis=0)   # (C, hd)
+        v = jnp.concatenate([r[0].reshape(page_size * KV, hd)
+                             for r in v_refs], axis=0)
+        q = q_ref[0].reshape(R, hd).astype(dt)
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+        k_pos = j * T + col // KV
+        q_abs = qstart_ref[b] + (row % QG) // G
+        ok = ((g * H + row // QG == col % KV)          # own kv head
+              & (k_pos <= q_abs) & (k_pos < length))   # causal + live tail
         if window:  # sliding window from each query's own position
             ok &= (q_abs - k_pos) < window
-        for h in range(KV):  # static: one page DMA serves every kv head
-            q = q_ref[0, h].astype(jnp.float32)        # (Q*G, hd)
-            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page_size, hd)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale
-            s = jnp.where(ok, s, NEG_INF)
-            m_prev, l_prev = m_ref[h], l_ref[h]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            probs = jnp.exp(s - m_cur[:, None])
-            alpha = jnp.exp(m_prev - m_cur)
-            acc_ref[h] = acc_ref[h] * alpha[:, None] + jax.lax.dot(probs, v)
-            m_ref[h] = m_cur
-            l_ref[h] = l_prev * alpha + jnp.sum(probs, axis=1)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        probs = jnp.where(ok, jnp.exp(s - m_cur), 0.0)
+        alpha = jnp.exp(m_prev - m_cur)
+        parts = _exact_parts(probs, dt)
+        pv = jax.lax.dot(jnp.concatenate(parts, axis=0), v,
+                         preferred_element_type=jnp.float32)
+        pv = sum(pv[i * R:(i + 1) * R] for i in range(len(parts)))
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_cur
+        l_ref[...] = l_prev * alpha + jnp.sum(probs, axis=1, keepdims=True)
 
-    @pl.when((p == n_live - 1) & (length > 0))
+    @pl.when((j == n_blocks - 1) & (length > 0))
     def _done():
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe[:, :, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe).reshape(H, QG, hd).astype(
+            o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -122,44 +199,86 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     assert (KV, hd) == (KVp, hdp) and v_pages.shape == k_pages.shape
     assert QG % q_span == 0, (QG, q_span)
     P = block_table.shape[1]
+    H, ppb = _block_sizes(page_size, KV, QG, P)
     sm_scale = 1.0 / math.sqrt(hd)
     lengths = lengths.astype(jnp.int32)
     if q_start is None:
         q_start = lengths - q_span
 
-    def kv_map(b, p, table, lens, qstart):
-        n_live = _live_pages(lens[b], page_size)
-        pc = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        return (jnp.maximum(table[b, pc], 0), 0, 0, 0)
+    pages = _fetch_plan(block_table, lengths, page_size, ppb)
 
-    def q_map(b, p, table, lens, qstart):
-        return (b, 0, 0, 0)
+    def kv_map(i):
+        return lambda b, g, j, pages, lens, qstart: (pages[b, j * ppb + i],
+                                                     0, 0, 0)
 
+    def q_map(b, g, j, pages, lens, qstart):
+        return (b, g, 0, 0)
+
+    kv_specs = [pl.BlockSpec((1, page_size, KV, hd), kv_map(i))
+                for i in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, KV, QG, hd), q_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, KV, QG, hd), q_map),
+        grid=(B, KV // H, pl.cdiv(P, ppb)),
+        in_specs=[pl.BlockSpec((1, H, QG, hd), q_map)] + kv_specs * 2,
+        out_specs=pl.BlockSpec((1, H, QG, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((KV, QG, hd), jnp.float32),
-            pltpu.VMEM((KV, QG), jnp.float32),
-            pltpu.VMEM((KV, QG), jnp.float32),
+            pltpu.VMEM((H * QG, hd), jnp.float32),
+            pltpu.VMEM((H * QG, 1), jnp.float32),
+            pltpu.VMEM((H * QG, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, sm_scale=sm_scale,
-                               page_size=page_size, window=window,
+                               page_size=page_size, ppb=ppb, window=window,
                                q_span=q_span)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, QG, hd), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), lengths,
-      q_start.astype(jnp.int32), q, k_pages, v_pages)
+    )(pages, lengths, q_start.astype(jnp.int32), q,
+      *[k_pages] * ppb, *[v_pages] * ppb)
+
+
+# (B, KV, G, hd, page_size, P, window, q_span[, lengths]): the first five
+# fit one block; the rest span three or more (`_block_sizes` gives 8
+# pages of 64 tokens), with lengths ending mid-block and on a block
+# boundary, a table width that is no multiple of the block, a sliding
+# window shorter than a block and Q > 1 spans that cross a block boundary;
+# the last, a chunk, takes one kv head a step
+SELFTEST_CASES = [
+    (3, 2, 4, 32, 8, 4, 0, 1),
+    (2, 1, 8, 64, 16, 3, 0, 1),
+    (4, 2, 2, 32, 8, 8, 16, 1),
+    (3, 2, 4, 32, 8, 4, 0, 3),
+    (2, 2, 2, 32, 8, 6, 16, 4),
+    (3, 2, 4, 32, 64, 26, 0, 1, (0, 1100, 1024)),
+    (3, 2, 4, 32, 64, 26, 100, 1, (0, 1600, 700)),
+    (3, 2, 2, 32, 64, 30, 0, 4, (0, 1026, 1537)),
+    (2, 2, 2, 32, 64, 27, 150, 5, (514, 1700)),
+    (2, 2, 4, 32, 64, 8, 0, 130, (0, 400)),
+]
+
+
+def random_case(case, rng, dtype=jnp.float32):
+    """Inputs for one case: q, pools, a block table over shuffled physical
+    pages and the lengths (random in [q_span, P*page_size] unless given)."""
+    import numpy as np
+
+    B, KV, G, hd, ps, P, _, Q, *fixed = case
+    N = B * P + 1
+    q = jnp.asarray(rng.standard_normal((B, KV, Q * G, hd)), dtype)
+    kp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), dtype)
+    lengths = (np.asarray(fixed[0]) if fixed
+               else rng.integers(Q, P * ps + 1, size=B))
+    perm = rng.permutation(np.arange(1, N))  # pages deliberately shuffled
+    table = np.full((B, P), -1, np.int32)
+    used = 0
+    for b in range(B):
+        n = -(-int(lengths[b]) // ps)
+        table[b, :n] = perm[used: used + n]
+        used += n
+    return q, kp, vp, jnp.asarray(table), jnp.asarray(lengths, jnp.int32)
 
 
 def _selftest() -> None:
@@ -169,36 +288,21 @@ def _selftest() -> None:
     from . import ref
 
     rng = np.random.default_rng(0)
-    for (B, KV, G, hd, ps, P, win, Q) in [(3, 2, 4, 32, 8, 4, 0, 1),
-                                          (2, 1, 8, 64, 16, 3, 0, 1),
-                                          (4, 2, 2, 32, 8, 8, 16, 1),
-                                          (3, 2, 4, 32, 8, 4, 0, 3),
-                                          (2, 2, 2, 32, 8, 6, 16, 4)]:
-        N = B * P + 1
-        q = jnp.asarray(rng.standard_normal((B, KV, Q * G, hd)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.float32)
-        lengths = rng.integers(Q, P * ps + 1, size=B)
-        if Q == 1:
-            lengths[rng.integers(B)] = 0  # keep an inactive row in the mix
-        perm = rng.permutation(np.arange(1, N))  # pages deliberately shuffled
-        table = np.full((B, P), -1, np.int32)
-        used = 0
-        for b in range(B):
-            n = -(-int(lengths[b]) // ps)
-            table[b, :n] = perm[used: used + n]
-            used += n
-        out = paged_attention(q, kp, vp, jnp.asarray(table),
-                              jnp.asarray(lengths, jnp.int32), window=win,
+    for case in SELFTEST_CASES:
+        B, KV, G, hd, ps, P, win, Q, *fixed = case
+        q, kp, vp, table, lengths = random_case(case, rng)
+        if Q == 1 and not fixed:
+            lengths = lengths.at[rng.integers(B)].set(0)  # an inactive row
+        out = paged_attention(q, kp, vp, table, lengths, window=win,
                               q_span=Q, interpret=True)
-        want = ref.paged_attention_ref(q, kp, vp, jnp.asarray(table),
-                                       jnp.asarray(lengths, jnp.int32),
+        want = ref.paged_attention_ref(q, kp, vp, table, lengths,
                                        window=win, q_span=Q)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
+        H, ppb = _block_sizes(ps, KV, Q * G, P)
         print(f"paged_attention parity OK: B={B} KV={KV} G={G} hd={hd} "
-              f"ps={ps} P={P} window={win} q_span={Q} "
-              f"lengths={lengths.tolist()}")
+              f"ps={ps} P={P} blocks={-(-P // ppb)}x{ppb} heads={H} window={win} "
+              f"q_span={Q} lengths={np.asarray(lengths).tolist()}")
 
 
 if __name__ == "__main__":

@@ -11,7 +11,10 @@ from jax import set_mesh
 from repro.configs import get_config, smoke_variant
 from repro.core import ElasticScalingPolicy, ScaleEvent
 from repro.kernels import ref
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (_block_sizes, _exact_parts,
+                                           _fetch_plan,
+                                           paged_attention,
+                                           random_case)
 from repro.serve import (PageAllocator, PageError, ServeEngine,
                          synthetic_requests)
 from repro.serve.engine import _lru_get
@@ -127,7 +130,7 @@ def test_table_array_only_and_width_checks():
 # ---------------------------------------------------------------------------
 
 PAGED_CASES = [
-    # (B, KV, G, hd, ps, P, window, q_span)
+    # (B, KV, G, hd, ps, P, window, q_span[, lengths])
     (3, 2, 4, 32, 8, 4, 0, 1),
     (2, 1, 8, 64, 16, 3, 0, 1),
     (4, 2, 2, 32, 8, 8, 0, 1),
@@ -135,35 +138,77 @@ PAGED_CASES = [
     (3, 2, 4, 32, 8, 4, 0, 3),  # Q>1: speculative verify spans
     (2, 1, 8, 64, 16, 3, 0, 5),
     (3, 2, 2, 32, 8, 6, 16, 4),  # Q>1 + sliding window
+    # three or more blocks of 8 pages of 64 tokens; row 0 has length 0
+    (3, 2, 4, 32, 64, 26, 0, 1, (0, 1100, 1024)),  # mid-block; on a boundary
+    (3, 2, 4, 32, 64, 26, 0, 1, (0, 1536, 513)),  # on the width's boundary
+    (3, 2, 4, 32, 64, 26, 100, 1, (0, 1600, 700)),  # window < a block
+    (3, 2, 2, 32, 64, 30, 0, 4, (0, 1026, 1537)),  # spans cross a boundary
+    (3, 2, 2, 32, 64, 27, 150, 5, (0, 514, 1700)),  # spans + window
+    (2, 2, 4, 32, 64, 8, 0, 130, (0, 400)),  # a chunk: one kv head a step
 ]
 
 
 @pytest.mark.parametrize("case", PAGED_CASES)
 def test_paged_attention_kernel_parity(case):
-    B, KV, G, hd, ps, P, window, Q = case
-    rng = np.random.default_rng(1)
-    N = B * P + 1
-    q = jnp.asarray(rng.standard_normal((B, KV, Q * G, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.float32)
-    lengths = rng.integers(Q, P * ps + 1, size=B)
-    lengths[0] = 0  # inactive row must return zeros
-    perm = rng.permutation(np.arange(1, N))
-    table = np.full((B, P), -1, np.int32)
-    used = 0
-    for b in range(B):
-        n = -(-int(lengths[b]) // ps)
-        table[b, :n] = perm[used: used + n]
-        used += n
-    out = paged_attention(q, kp, vp, jnp.asarray(table),
-                          jnp.asarray(lengths, jnp.int32), window=window,
+    B, KV, G, hd, ps, P, window, Q, *fixed = case
+    heads, ppb = _block_sizes(ps, KV, Q * G, P)
+    if fixed:  # the multi-block cases must stay multi-block
+        assert -(-P // ppb) >= 3
+    if Q == 130:
+        assert heads == 1
+    q, kp, vp, table, lengths = random_case(case, np.random.default_rng(1))
+    lengths = lengths.at[0].set(0)  # inactive row must return zeros
+    out = paged_attention(q, kp, vp, table, lengths, window=window,
                           q_span=Q, interpret=True)
-    want = ref.paged_attention_ref(q, kp, vp, jnp.asarray(table),
-                                   jnp.asarray(lengths, jnp.int32),
+    want = ref.paged_attention_ref(q, kp, vp, table, lengths,
                                    window=window, q_span=Q)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     assert np.all(np.asarray(out)[0] == 0.0)
+
+
+def test_fetch_plan_reads_only_owned_pages_and_repeats_past_the_tail():
+    """Operand i fetches the table's page while it is live and then repeats
+    its own last live page (no new DMA); no row reads a page it does not
+    own, and a row with no live page reads the null page 0."""
+    ps, ppb = 4, 3
+    table = np.array([[5, 9, 2, 7, 11, 4, -1, -1],  # 6 pages of 8
+                      [3, -1, -1, -1, -1, -1, -1, -1],  # 1 page
+                      [-1] * 8], np.int32)  # length 0
+    lengths = np.array([23, 2, 0], np.int32)
+    plan = np.asarray(_fetch_plan(jnp.asarray(table), jnp.asarray(lengths),
+                                  ps, ppb))
+    assert plan.shape == (3, 9)  # ceil(8 / 3) blocks of 3 operands
+    np.testing.assert_array_equal(plan[0], [5, 9, 2, 7, 11, 4, 7, 11, 4])
+    np.testing.assert_array_equal(plan[1], [3, 3, 3, 3, 3, 3, 3, 3, 3])
+    np.testing.assert_array_equal(plan[2], [0] * 9)
+
+
+def test_exact_parts_sum_back_to_float32():
+    """The bfloat16 terms of P sum back to P exactly, so P.V through them is
+    P.V in float32."""
+    p = jax.random.uniform(jax.random.PRNGKey(0), (64, 512), jnp.float32)
+    p = p * jnp.exp(-30.0 * p)  # softmax-like spread of magnitudes
+    parts = _exact_parts(p, jnp.bfloat16)
+    assert all(x.dtype == jnp.bfloat16 for x in parts)
+    total = sum(x.astype(jnp.float32) for x in parts)
+    np.testing.assert_array_equal(np.asarray(total), np.asarray(p))
+
+
+def test_paged_attention_kernel_bf16_pool():
+    """A bfloat16 pool over three blocks matches the float32 oracle to the
+    output's own rounding."""
+    P = 3 * _block_sizes(16, 8, 4, 1 << 10)[1] - 2  # danube's heads, Q=1
+    case = (2, 8, 4, 80, 16, P, 0, 1, (300, 16 * P - 5))
+    q, kp, vp, table, lengths = random_case(case, np.random.default_rng(2),
+                                            jnp.bfloat16)
+    out = paged_attention(q, kp, vp, table, lengths, interpret=True)
+    with jax.default_matmul_precision("highest"):
+        want = ref.paged_attention_ref(*(x.astype(jnp.float32)
+                                         for x in (q, kp, vp)),
+                                       table, lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), rtol=8e-3, atol=8e-3)
 
 
 def test_paged_attention_q_span_matches_sequential_refs():

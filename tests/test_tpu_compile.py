@@ -47,14 +47,17 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
+_HEADS = (_DANUBE.kv_heads(), _DANUBE.num_heads // _DANUBE.kv_heads(),
+          _DANUBE.head_dim_())
+
 # (B, KV, G, hd, page_size, pages per sequence, Q, dtype)
 _SHAPES = {
-    "danube_q1": (8, _DANUBE.kv_heads(),
-                  _DANUBE.num_heads // _DANUBE.kv_heads(),
-                  _DANUBE.head_dim_(), 16, 32, 1, jnp.bfloat16),
-    "danube_q5": (8, _DANUBE.kv_heads(),
-                  _DANUBE.num_heads // _DANUBE.kv_heads(),
-                  _DANUBE.head_dim_(), 16, 32, 5, jnp.bfloat16),
+    "danube_q1": (8, *_HEADS, 16, 32, 1, jnp.bfloat16),
+    "danube_q5": (8, *_HEADS, 16, 32, 5, jnp.bfloat16),
+    # danube-serve-long's decode: 22 slots, a 256-page table
+    "danube_serve_long": (22, *_HEADS, 16, 256, 1, jnp.bfloat16),
+    # a chunked-prefill chunk of 256 tokens
+    "danube_chunk256": (4, *_HEADS, 16, 256, 256, jnp.bfloat16),
     "smoke": (3, 2, 4, 32, 8, 4, 1, jnp.float32),
 }
 
