@@ -3,6 +3,7 @@ from .base import (
     ModelConfig,
     ShapeConfig,
     TrainConfig,
+    YarnRope,
     get_config,
     list_archs,
     register,
@@ -14,6 +15,7 @@ __all__ = [
     "ModelConfig",
     "ShapeConfig",
     "TrainConfig",
+    "YarnRope",
     "get_config",
     "list_archs",
     "register",

@@ -37,6 +37,21 @@ INPUT_SHAPES: Dict[str, ShapeConfig] = {
 
 
 @dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling (arXiv:2309.00071), in the form of the Hugging
+    Face `rope_type: yarn` parameters: frequencies are interpolated by
+    `factor` below the `beta_slow` rotation count over the original
+    context, kept above `beta_fast`, ramped linearly between, and cos/sin
+    are scaled by `attention_factor`."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | hybrid | ssm | audio | vlm
@@ -55,6 +70,12 @@ class ModelConfig:
     qkv_bias: bool = False
     sliding_window: int = 0  # 0 = full attention (arch-native)
     rope_theta: float = 10000.0
+    # attention kind per layer: one period of per-layer windows (0 = full
+    # attention), repeated over the depth; () = `sliding_window` on every
+    # layer.  The full-attention layers of the period use `rope_yarn`.
+    layer_windows: Tuple[int, ...] = ()
+    rope_yarn: Optional[YarnRope] = None
+    tie_embeddings: bool = True  # False: a separate output head
 
     # MoE
     num_experts: int = 0
@@ -62,7 +83,11 @@ class ModelConfig:
     moe_every: int = 1  # MoE FFN on every k-th layer (jamba: 2)
     moe_dense_residual: bool = False  # arctic: dense FFN residual alongside MoE
     dense_residual_ff: int = 0  # width of arctic's dense residual FFN
-    moe_capacity_factor: float = 1.25  # dispatch buffer slack (perf knob)
+    # (first, count): the experts of each MoE layer this chip holds (its
+    # share of an expert-parallel deployment); () = all `num_experts`.
+    # The router scores all `num_experts`; the layer computes the part of
+    # the result its held experts give.
+    experts_held: Tuple[int, int] = ()
 
     # hybrid (jamba): 1 attention layer per `attn_every` layers, rest Mamba
     attn_every: int = 0
@@ -95,17 +120,25 @@ class ModelConfig:
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts this chip holds."""
+        return self.experts_held or (0, self.num_experts)
+
+    def period(self) -> int:
+        """Layers in one period of the attention-kind pattern."""
+        return len(self.layer_windows) or 1
+
     def n_params(self) -> int:
         """Analytic parameter count (embedding + blocks + head)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         hd = self.head_dim_()
         nq, nkv = self.num_heads, self.kv_heads()
-        n = v * d  # embedding (tied head)
+        n = v * d if self.tie_embeddings else 2 * v * d  # embedding + head
         per_attn = d * nq * hd + 2 * d * nkv * hd + nq * hd * d
         if self.qkv_bias:
             per_attn += (nq + 2 * nkv) * hd
         per_mlp = 3 * d * f  # swiglu
-        per_moe = self.num_experts * 3 * d * f + d * self.num_experts
+        per_moe = self.held_experts()[1] * 3 * d * f + d * self.num_experts
         if self.moe_dense_residual:
             per_moe += 3 * d * (self.dense_residual_ff or f)
         d_inner = self.ssm_expand * d
@@ -145,7 +178,7 @@ class ModelConfig:
         if not self.num_experts:
             return self.n_params()
         d, f = self.d_model, self.d_ff
-        inactive = (self.num_experts - self.experts_per_token) * 3 * d * f
+        inactive = (self.held_experts()[1] - self.experts_per_token) * 3 * d * f
         n_moe_layers = self.num_layers // max(self.moe_every, 1)
         return self.n_params() - n_moe_layers * inactive
 
@@ -192,21 +225,28 @@ def list_archs() -> Tuple[str, ...]:
 
 
 def smoke_variant(cfg: ModelConfig) -> ModelConfig:
-    """Reduced config of the same family: 2 layers, d_model<=512, <=4 experts."""
+    """Reduced config of the same family: 2 layers (one whole period of the
+    attention-kind pattern where that is longer), d_model<=512, <=4
+    experts, top-k at most half of them, and half of them held where the
+    config holds a share."""
     d = min(cfg.d_model, 256)
     hd = 32
     heads = max(2, min(4, cfg.num_heads))
     kv = max(1, min(heads, cfg.kv_heads(), 2))
+    n_exp = min(cfg.num_experts, 4)
     updates = dict(
         name=cfg.name + "-smoke",
-        num_layers=2,
+        num_layers=max(2, len(cfg.layer_windows)),
         d_model=d,
         num_heads=heads,
         num_kv_heads=kv,
         head_dim=hd,
         d_ff=2 * d,
         vocab_size=min(cfg.vocab_size, 512) or 512,
-        num_experts=min(cfg.num_experts, 4),
+        num_experts=n_exp,
+        experts_per_token=min(cfg.experts_per_token, max(1, n_exp // 2)),
+        experts_held=((0, max(1, n_exp // 2)) if cfg.experts_held else ()),
+        layer_windows=tuple(min(w, 16) for w in cfg.layer_windows),
         dense_residual_ff=min(cfg.dense_residual_ff, 2 * d),
         encoder_layers=2 if cfg.encoder_layers else 0,
         encoder_seq=16 if cfg.encoder_seq else 0,
@@ -240,4 +280,5 @@ def _ensure_loaded() -> None:
         qwen3_4b,
         qwen1_5_4b,
         chicle_paper,
+        mellum2_12b_a2_5b,
     )

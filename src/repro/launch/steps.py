@@ -34,14 +34,16 @@ def decode_geometry(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
 
     long_500k requires sub-quadratic attention: SSM/hybrid state is O(1);
     attention layers fall back to the arch's sliding window, or the
-    `swa-variant` window for full-attention archs (DESIGN.md §4).
+    `swa-variant` window for full-attention archs (DESIGN.md §4).  An arch
+    with a window per layer (`layer_windows`) keeps its own, full layers
+    included, and takes no override.
     """
-    window = cfg.sliding_window
+    window = 0 if cfg.layer_windows else cfg.sliding_window
     cache_len = shape.seq_len
     ring = False
     variant = "native"
     if shape.name == "long_500k":
-        if not window and not cfg.is_attention_free():
+        if not (window or cfg.is_attention_free() or cfg.layer_windows):
             window = cfg.long_context_window
             if cfg.family != "hybrid":
                 variant = "swa-variant"

@@ -125,9 +125,9 @@ def qkv_project(cfg: ModelConfig, p, x: jax.Array,
         k = rms_norm(k, p["k_norm"])
     if positions is not None:
         if rope_q:
-            q = rope(q, positions, cfg.rope_theta)
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_yarn)
         if rope_k:
-            k = rope(k, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_yarn)
     return q, k, v
 
 

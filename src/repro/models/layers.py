@@ -81,20 +81,47 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x32 * jax.lax.rsqrt(var + eps)).astype(dt) * w
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_frequencies(hd: int, theta: float, yarn) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies (hd/2,), cos/sin scale) of YaRN RoPE, as the
+    Hugging Face `rope_type: yarn` computes them (correction range
+    truncated to whole dims)."""
+    half = hd // 2
+    base = theta ** (np.arange(half, dtype=np.float64) / half)
+
+    def dim_of(rotations):  # dim whose wavelength fits `rotations` turns
+        return (hd * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(yarn.beta_slow)), hd - 1)
+    ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp  # 1: extrapolate (the base frequency), 0: interpolate
+    inv = (1.0 / (yarn.factor * base)) * (1.0 - keep) + (1.0 / base) * keep
+    return inv.astype(np.float32), float(yarn.attention_factor)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         yarn=None) -> jax.Array:
     """Rotary embedding. x: (..., S, ..., hd); positions: (..., S) broadcastable.
 
     We apply over the last dim with positions broadcast from axis carrying S.
     x shape convention here: (B, S, KV, G, hd) or (B, S, KV, hd); positions (B, S).
+    yarn: optional `configs.YarnRope` scaling of the frequencies.
     """
     hd = x.shape[-1]
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        freqs, scale = yarn_frequencies(hd, theta, yarn)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (B, S, half)
     # insert head-ish axes between S and hd so ang broadcasts against x
     for _ in range(x.ndim - 3):
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

@@ -34,6 +34,9 @@ def param_defs(cfg: ModelConfig) -> Dict[str, Any]:
                           init="small"),
         "final_ln": ParamDef((cfg.d_model,), (None,), init="ones"),
     }
+    if not cfg.tie_embeddings:
+        defs["head"] = ParamDef((cfg.vocab_size, cfg.d_model),
+                                ("tensor", "fsdp"), init="small")
     if cfg.family == "audio":
         defs["blocks"] = jax.tree.map(
             lambda d: d.stacked(cfg.num_layers),
@@ -82,7 +85,75 @@ def _embed(cfg, params, tokens):
 
 def _logits(cfg, params, x):
     x = rms_norm(x, params["final_ln"])
-    return jnp.einsum("bsd,vd->bsv", x, params["embed"])
+    head = params["embed"] if cfg.tie_embeddings else params["head"]
+    return jnp.einsum("bsd,vd->bsv", x, head)
+
+
+def _scan_layers(cfg: ModelConfig, window: Optional[int], make_body, x,
+                 blocks, state=None):
+    """Run the stacked layers over x.  `make_body(layer_cfg, window)` gives
+    `body(x, (layer params, layer state)) -> (x, (new layer state,
+    extras))`; `blocks`, `state` and the extras are stacked over layers
+    (`state` may be None).  Returns (x, new state, extras).
+
+    One kind of attention (`tfm.layer_kinds`): a scan of one block a step.
+    Per-layer kinds, or an MoE model: a scan of one period a step (one
+    layer for an MoE model of one kind), its layers unrolled, each traced
+    with its own window and RoPE; the state rides in the carry and each
+    layer's slice is updated in place.  An MoE layer's expert weights stay
+    whole, with the layer's index beside them: the grouped kernel reads
+    the layer's experts in place (a slice per step would copy them all).
+    """
+    kinds = tfm.layer_kinds(cfg, window)
+    experts = None
+    if cfg.family == "moe":
+        moe = dict(blocks["moe"])
+        experts = {k: moe.pop(k) for k in ("gate", "up", "down")}
+        blocks = dict(blocks, moe=moe)
+    elif len(kinds) == 1:
+        body = make_body(*kinds[0])
+        if state is None:
+            def body_nc(x, bp):
+                x, (_, ex) = body(x, (bp, None))
+                return x, ex
+            x, extras = jax.lax.scan(body_nc, x, blocks)
+            return x, None, extras
+        x, (state, extras) = jax.lax.scan(body, x, (blocks, state))
+        return x, state, extras
+
+    P = len(kinds)
+    bodies = [make_body(*k) for k in kinds]
+
+    def period(carry, bp):
+        x, st, j = carry
+        exs = []
+        for i, body in enumerate(bodies):
+            layer = j * P + i
+            sl = None if st is None else jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), st)
+            lp = jax.tree.map(lambda a: a[i], bp)
+            if experts is not None:
+                lp = dict(lp, moe=dict(lp["moe"], layer=layer, **experts))
+            x, (new_sl, ex) = body(x, (lp, sl))
+            if st is not None:
+                st = jax.tree.map(
+                    lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                        a, u.astype(a.dtype), layer, 0), st, new_sl)
+            exs.append(ex)
+        return (x, st, j + 1), jax.tree.map(lambda *e: jnp.stack(e), *exs)
+
+    grouped = jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // P, P) + a.shape[1:]), blocks)
+    (x, state, _), extras = jax.lax.scan(period, (x, state, jnp.int32(0)),
+                                         grouped)
+    extras = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), extras)
+    return x, state, extras
+
+
+def _sum_stats(stats):
+    """Per-layer MoE counters (L, N) -> their sum over layers (None for a
+    model without MoE layers)."""
+    return None if stats is None else jnp.sum(stats, axis=0)
 
 
 def _encoder_forward(cfg, enc_params, frames, rules, remat):
@@ -90,8 +161,8 @@ def _encoder_forward(cfg, enc_params, frames, rules, remat):
     x = frames
 
     def body(x, bp):
-        x, _, _ = tfm.block_apply(cfg, bp, x, None, block_type="dense",
-                                  causal=False, rules=rules)
+        x, _, _, _ = tfm.block_apply(cfg, bp, x, None, block_type="dense",
+                                     causal=False, rules=rules)
         return x, None
 
     if remat:
@@ -109,9 +180,23 @@ def forward(cfg: ModelConfig, params, tokens: jax.Array, *,
     memory: stub embeddings for vlm (patches) / audio (frames).
     Returns (logits, aux_loss) or (logits, aux_loss, cache) if return_cache.
     """
+    logits, aux, cache, _ = _forward(
+        cfg, params, tokens, memory=memory, rules=rules, window=window,
+        remat=remat, return_cache=return_cache, q_block=q_block)
+    if return_cache:
+        return logits, aux, cache
+    return logits, aux
+
+
+def _forward(cfg: ModelConfig, params, tokens: jax.Array, *,
+             memory: Optional[jax.Array], rules: AxisRules,
+             window: Optional[int], remat: bool, return_cache: bool,
+             q_block: int, valid: Optional[jax.Array] = None):
+    """`forward`, returning (logits, aux, cache or None, MoE counters
+    summed over layers or None).  valid: optional (B, S) bool of real
+    tokens (MoE layers compute no rows for the rest)."""
     B, S = tokens.shape
     bt = _block_type(cfg)
-    win = cfg.sliding_window if window is None else window
     x = _embed(cfg, params, tokens)
     if rules is not None:
         x = jax.lax.with_sharding_constraint(
@@ -121,45 +206,38 @@ def forward(cfg: ModelConfig, params, tokens: jax.Array, *,
     if cfg.family == "audio":
         memory = _encoder_forward(cfg, params["encoder"], memory, rules, remat)
 
-    dummy_cache = None
+    from ..sharding import constrain_fwd_only
+
+    def make_body(lcfg, win):
+        def body(x, xs):
+            bp, cache = xs
+            x, new_cache, aux, stats = tfm.block_apply(
+                lcfg, bp, x, positions, block_type=bt, window=win,
+                cache=cache, memory=memory, rules=rules, q_block=q_block,
+                valid=valid)
+            # primal-only: shrinks the saved residual stack (seq-parallel)
+            # without pinning the cotangent layout (see
+            # sharding.constrain_fwd_only)
+            if rules is not None:
+                x = constrain_fwd_only(x, rules.sharding("batch", "seq", None))
+            return x, (new_cache, (aux, stats))
+
+        return jax.checkpoint(body) if remat else body
+
+    caches = None
     if return_cache:
         shapes = tfm.block_cache_shapes(
             cfg, B, S, bt, cross_len=memory.shape[1] if memory is not None else 0)
-        dummy_cache = {k: jnp.zeros(s, d) for k, (s, d) in shapes.items()}
-
-    from ..sharding import constrain_fwd_only
-
-    def body(x, xs):
-        bp, cache = xs
-        x, new_cache, aux = tfm.block_apply(
-            cfg, bp, x, positions, block_type=bt, window=win, cache=cache,
-            memory=memory, rules=rules, q_block=q_block)
-        # primal-only: shrinks the saved residual stack (seq-parallel) without
-        # pinning the cotangent layout (see sharding.constrain_fwd_only)
-        if rules is not None:
-            x = constrain_fwd_only(x, rules.sharding("batch", "seq", None))
-        return x, (new_cache, aux)
-
-    if remat:
-        body = jax.checkpoint(body)
-
-    nb = tfm.n_blocks(cfg)
-    if return_cache:
+        dummy = {k: jnp.zeros(s, d) for k, (s, d) in shapes.items()}
+        nb = tfm.n_blocks(cfg)
         caches = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (nb,) + a.shape), dummy_cache)
-        x, (cache, auxs) = jax.lax.scan(body, x, (params["blocks"], caches))
-    else:
-        def body_nc(x, bp):
-            x, (_, aux) = body(x, (bp, None))
-            return x, aux
-        x, auxs = jax.lax.scan(body_nc, x, params["blocks"])
-        cache = None
+            lambda a: jnp.broadcast_to(a[None], (nb,) + a.shape), dummy)
+    x, cache, (auxs, stats) = _scan_layers(cfg, window, make_body, x,
+                                           params["blocks"], caches)
 
     logits = _logits(cfg, params, x)
     aux = jnp.sum(auxs) if auxs is not None else jnp.float32(0.0)
-    if return_cache:
-        return logits, aux, cache
-    return logits, aux
+    return logits, aux, cache, _sum_stats(stats)
 
 
 def loss_fn(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
@@ -242,7 +320,6 @@ def decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
     Returns (logits (B, 1, V), new cache).
     """
     bt = _block_type(cfg)
-    win = cfg.sliding_window if window is None else window
     x = _embed(cfg, params, token)
     if rules is not None:
         x = jax.lax.with_sharding_constraint(
@@ -267,13 +344,17 @@ def decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
     else:
         k_pos = None
 
-    def body(x, xs):
-        bp, bc = xs
-        x, new_bc = tfm.block_decode(cfg, bp, x, pos, k_pos, bc, block_type=bt,
-                                     window=win, ring=ring, rules=rules)
-        return x, new_bc
+    def make_body(lcfg, win):
+        def body(x, xs):
+            bp, bc = xs
+            x, new_bc = tfm.block_decode(lcfg, bp, x, pos, k_pos, bc,
+                                         block_type=bt, window=win,
+                                         ring=ring, rules=rules)
+            return x, (new_bc, None)
+        return body
 
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new_blocks, _ = _scan_layers(cfg, window, make_body, x,
+                                    params["blocks"], cache["blocks"])
     logits = _logits(cfg, params, x)
     new_cache = dict(cache, blocks=new_blocks)
     if k_pos is not None:
@@ -307,8 +388,8 @@ def paged_decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
                       pos: jax.Array, table: jax.Array, lengths: jax.Array,
                       *, rules: AxisRules, window: Optional[int] = None,
                       impl: str = "xla",
-                      cow: Optional[Tuple[jax.Array, jax.Array]] = None
-                      ) -> Tuple[jax.Array, Dict[str, Any]]:
+                      cow: Optional[Tuple[jax.Array, jax.Array]] = None,
+                      stats: bool = False):
     """One paged decode step.  token: (B, 1) int32; pos: (B,) per-row write
     positions; table: (B, P) block table; lengths: (B,) live tokens incl.
     this one (0 = inactive row, output garbage, writes -> null page).
@@ -317,23 +398,31 @@ def paged_decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
     share breaks fused into the scatter (see `transformer.block_decode_paged`;
     rows without a break pass the null page for both).
 
-    Returns (logits (B, 1, V), new cache)."""
-    win = cfg.sliding_window if window is None else window
+    Returns (logits (B, 1, V), new cache), and with `stats` (MoE models)
+    the (moe.N_STATS,) int32 expert counters summed over layers."""
     x = _embed(cfg, params, token)
     if rules is not None:
         x = jax.lax.with_sharding_constraint(
             x, rules.sharding("batch", None, None))
     q_pos = pos.astype(jnp.int32)[:, None]
+    x, new_blocks, moe = _scan_layers(
+        cfg, window, _paged_body(q_pos, table, lengths, rules, impl, cow), x,
+        params["blocks"], cache["blocks"])
+    out = (_logits(cfg, params, x), dict(cache, blocks=new_blocks))
+    return out + (_sum_stats(moe),) if stats else out
 
-    def body(x, xs):
-        bp, bc = xs
-        x, new_bc = tfm.block_decode_paged(cfg, bp, x, q_pos, table, lengths,
-                                           bc, window=win, rules=rules,
-                                           impl=impl, cow=cow)
-        return x, new_bc
 
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
-    return _logits(cfg, params, x), dict(cache, blocks=new_blocks)
+def _paged_body(q_pos, table, lengths, rules, impl, cow=None):
+    """`make_body` for `_scan_layers` over `transformer.block_decode_paged`."""
+    def make_body(lcfg, win):
+        def body(x, xs):
+            bp, bc = xs
+            x, new_bc, moe = tfm.block_decode_paged(
+                lcfg, bp, x, q_pos, table, lengths, bc, window=win,
+                rules=rules, impl=impl, cow=cow)
+            return x, (new_bc, moe)
+        return body
+    return make_body
 
 
 def paged_prefill_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
@@ -353,7 +442,6 @@ def paged_prefill_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
     long prompt amortizes over many engine ticks instead of stalling one.
 
     Returns (last-token logits (B, 1, V), new cache)."""
-    win = cfg.sliding_window if window is None else window
     B, C = tokens.shape
     x = _embed(cfg, params, tokens)
     if rules is not None:
@@ -361,15 +449,9 @@ def paged_prefill_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
             x, rules.sharding("batch", None, None))
     q_pos = (offset.astype(jnp.int32)[:, None]
              + jnp.arange(C, dtype=jnp.int32)[None])
-
-    def body(x, xs):
-        bp, bc = xs
-        x, new_bc = tfm.block_decode_paged(cfg, bp, x, q_pos, table,
-                                           chunk_end, bc, window=win,
-                                           rules=rules, impl=impl)
-        return x, new_bc
-
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new_blocks, _ = _scan_layers(
+        cfg, window, _paged_body(q_pos, table, chunk_end, rules, impl), x,
+        params["blocks"], cache["blocks"])
     logits = _logits(cfg, params, x)
     last = jnp.clip(chunk_end - offset - 1, 0, C - 1).astype(jnp.int32)
     last_logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)
@@ -403,7 +485,6 @@ def verify_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
     if bt not in ("dense", "moe"):
         raise NotImplementedError(f"verify supports dense/moe; got {bt!r}")
     B, Q = tokens.shape
-    win = cfg.sliding_window if window is None else window
     q_pos = pos.astype(jnp.int32)[:, None] + jnp.arange(Q, dtype=jnp.int32)
     valid = jnp.arange(Q, dtype=jnp.int32)[None] < n_new[:, None]
     x = _embed(cfg, params, tokens)
@@ -423,13 +504,16 @@ def verify_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
     idx = jnp.where(valid, q_pos, W)
     k_pos = k_pos.at[rows, idx].set(q_pos, mode="drop")
 
-    def body(x, xs):
-        bp, bc = xs
-        x, new_bc = tfm.block_verify(cfg, bp, x, q_pos, valid, k_pos, bc,
-                                     window=win, rules=rules)
-        return x, new_bc
+    def make_body(lcfg, win):
+        def body(x, xs):
+            bp, bc = xs
+            x, new_bc = tfm.block_verify(lcfg, bp, x, q_pos, valid, k_pos,
+                                         bc, window=win, rules=rules)
+            return x, (new_bc, None)
+        return body
 
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new_blocks, _ = _scan_layers(cfg, window, make_body, x,
+                                    params["blocks"], cache["blocks"])
     logits = _logits(cfg, params, x)
     return logits, dict(cache, blocks=new_blocks, k_pos=k_pos)
 
@@ -453,22 +537,15 @@ def paged_verify_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
     positions.  cow: optional (src, dst) copy-on-write page pairs (only the
     span's FIRST page can be shared, so one pair per row suffices).
     Returns (logits (B, Q, V), new cache)."""
-    win = cfg.sliding_window if window is None else window
     B, Q = tokens.shape
     x = _embed(cfg, params, tokens)
     if rules is not None:
         x = jax.lax.with_sharding_constraint(
             x, rules.sharding("batch", None, None))
     q_pos = pos.astype(jnp.int32)[:, None] + jnp.arange(Q, dtype=jnp.int32)
-
-    def body(x, xs):
-        bp, bc = xs
-        x, new_bc = tfm.block_decode_paged(cfg, bp, x, q_pos, table, lengths,
-                                           bc, window=win, rules=rules,
-                                           impl=impl, cow=cow)
-        return x, new_bc
-
-    x, new_blocks = jax.lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new_blocks, _ = _scan_layers(
+        cfg, window, _paged_body(q_pos, table, lengths, rules, impl, cow), x,
+        params["blocks"], cache["blocks"])
     return _logits(cfg, params, x), dict(cache, blocks=new_blocks)
 
 
@@ -476,7 +553,7 @@ def prefill(cfg: ModelConfig, params, tokens: jax.Array, *,
             memory: Optional[jax.Array] = None, rules: AxisRules,
             window: Optional[int] = None, remat: bool = True,
             q_block: int = 512, cache_len: Optional[int] = None,
-            true_len: Optional[jax.Array] = None):
+            true_len: Optional[jax.Array] = None, stats: bool = False):
     """Prefill: forward over the prompt, returning last-token logits + a
     decode cache.  cache_len > S allocates headroom for subsequent decode
     steps (k/v seq dims zero-padded, empty slots marked -1 in k_pos).
@@ -484,10 +561,21 @@ def prefill(cfg: ModelConfig, params, tokens: jax.Array, *,
     true_len: optional (B,) real prompt lengths when rows are right-padded to
     a shared bucket length (serving).  Returned logits are taken at each
     row's true last token and k_pos becomes per-row (B, cache_len) with pad
-    positions masked out (-1), matching ``init_cache(per_slot=True)``."""
-    logits, aux, blocks = forward(cfg, params, tokens, memory=memory,
-                                  rules=rules, window=window, remat=remat,
-                                  return_cache=True, q_block=q_block)
+    positions masked out (-1), matching ``init_cache(per_slot=True)``.
+
+    stats: also return the (moe.N_STATS,) int32 expert counters summed
+    over layers (MoE models), pad tokens past true_len left out."""
+    B, S = tokens.shape
+    valid = (None if true_len is None or not cfg.num_experts else
+             jnp.arange(S, dtype=jnp.int32)[None] < true_len[:, None])
+    logits, aux, blocks, moe = _forward(
+        cfg, params, tokens, memory=memory, rules=rules, window=window,
+        remat=remat, return_cache=True, q_block=q_block, valid=valid)
+    out = _prefill_out(cfg, logits, blocks, tokens, cache_len, true_len)
+    return out + (moe,) if stats else out
+
+
+def _prefill_out(cfg, logits, blocks, tokens, cache_len, true_len):
     B, S = tokens.shape
     bt = _block_type(cfg)
     cache_len = cache_len or S

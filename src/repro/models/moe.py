@@ -1,34 +1,43 @@
-"""Mixture-of-Experts FFN: grouped top-k routing with capacity buffers.
+"""Mixture-of-Experts FFN: dropless top-k routing over the experts a chip
+holds.
 
-GShard/MaxText-style TPU formulation: tokens are reshaped to
-(G groups, T_g tokens, D) with G sharded over the batch ("data") axes, so
-every dispatch tensor keeps a sharded leading dim and nothing rematerializes
-at global size.  Within a group, top-k assignments get slots in per-expert
-capacity buffers via a cumsum; overflow tokens are DROPPED (static shapes).
+The router scores every one of the config's `num_experts` experts;
+softmax over all of them, top-k, and the k weights renormalised to sum to
+one.  This chip holds experts [first, first + count) (`cfg.held_experts`,
+its share of an expert-parallel deployment; all of them by default) and
+computes only its held experts' part of the result: the (token, expert)
+rows routed to a held expert are sorted by expert and run through the
+grouped Pallas kernel (`kernels.grouped_matmul.expert_matmul`), each
+weighted by its gate, and summed back per token.  No row is ever dropped
+(there are no capacity buffers); rows routed to experts held elsewhere,
+and rows of tokens the caller marks invalid (padding, idle decode slots),
+sort last and are not computed.  What the absent experts would add is
+left out: this layer's output is the partial sum its experts give.
 
-Expert weights shard "expert"->model when E divides the model axis
-(expert parallelism: arctic 128, jamba 16); otherwise d_ff->model
-(tensor-parallel experts: grok 8).  The (G->data, E->model) buffer layout
-makes the dispatch gather/scatter lower to the all-to-all-ish collectives
-we examine in the roofline.
-
-Returns a Switch-style load-balance aux loss for the trainer.
+Returns the output, a Switch-style load-balance aux loss for the
+trainer, and the call's counters (`N_STATS` int32).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from ..sharding import AxisRules
+from ..kernels import grouped_matmul as gm
 from .layers import ParamDef
+
+# counters of one layer call, int32: rows routed to held experts (valid
+# tokens only), rows the grouped kernel computes (tile padding included),
+# held experts with at least one row
+N_STATS = 3
 
 
 def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
     d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
-    expert_parallel = e % 16 == 0  # big-E archs shard the expert dim
+    held = cfg.held_experts()[1]
+    expert_parallel = held % 16 == 0  # many experts: shard the expert dim
     if expert_parallel:
         axes3 = ("tensor", "fsdp", None)  # (E, D, F): E -> model
         axes3b = ("tensor", None, "fsdp")  # (E, F, D)
@@ -37,9 +46,9 @@ def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
         axes3b = (None, "tensor", "fsdp")
     defs = {
         "router": ParamDef((d, e), ("fsdp", None), init="small"),
-        "gate": ParamDef((e, d, f), axes3),
-        "up": ParamDef((e, d, f), axes3),
-        "down": ParamDef((e, f, d), axes3b),
+        "gate": ParamDef((held, d, f), axes3),
+        "up": ParamDef((held, d, f), axes3),
+        "down": ParamDef((held, f, d), axes3b),
     }
     if cfg.moe_dense_residual:
         fr = cfg.dense_residual_ff or f
@@ -49,101 +58,70 @@ def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
     return defs
 
 
-def _constrain(x: jax.Array, rules: AxisRules, *axes) -> jax.Array:
-    if rules is None:
-        return x
-    from jax.sharding import NamedSharding
-    spec = rules.guard(rules.spec(*axes), x.shape)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(rules.mesh, spec))
+def moe_ffn(cfg: ModelConfig, p, x: jax.Array, *,
+            valid: Optional[jax.Array] = None
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, S, D); valid: optional (B, S) bool, False for tokens whose
+    output is never read (their rows are not computed).
 
+    Returns (out (B, S, D), aux_loss scalar, stats (N_STATS,) int32)."""
+    from ..kernels.ops import interpret_mode
 
-def _n_groups(rules: AxisRules, B: int) -> int:
-    g = rules.axis_size(rules.batch) if rules is not None else 1
-    while g > 1 and B % g:
-        g //= 2
-    return max(g, 1)
-
-
-def moe_ffn(cfg: ModelConfig, p, x: jax.Array, rules: AxisRules,
-            *, capacity_factor: float = 0.0) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar)."""
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.experts_per_token
+    first, held = cfg.held_experts()
+    T = B * S
+    with jax.named_scope("moe"):
+        xt = x.reshape(T, D)
+        # float32 scores: a bfloat16 rounding would flip near-tied choices
+        logits = jnp.einsum("td,de->te", xt, p["router"],
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = jax.lax.top_k(probs, K)
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
 
-    if rules is not None:
-        # per-step expert weight grads must be BORN sharded (see pin_grad)
-        from jax.sharding import NamedSharding
-        from ..sharding import pin_grad
-        ep = E % 16 == 0
-        axes3 = ("tensor", "fsdp", None) if ep else (None, "fsdp", "tensor")
-        axes3b = ("tensor", None, "fsdp") if ep else (None, "tensor", "fsdp")
-        from ..sharding import use_weight
-        p = dict(p)
-        for k_, ax in (("gate", axes3), ("up", axes3), ("down", axes3b)):
-            spec = rules.guard(rules.spec(*ax), p[k_].shape)
-            p[k_] = pin_grad(p[k_], NamedSharding(rules.mesh, spec))
-            p[k_] = use_weight(p[k_], rules, *ax)
-        for k_, ax in (("res_gate", ("fsdp", "tensor")),
-                       ("res_up", ("fsdp", "tensor")),
-                       ("res_down", ("tensor", "fsdp"))):
-            if k_ in p:
-                p[k_] = use_weight(p[k_], rules, *ax)
-    G = _n_groups(rules, B)
-    Tg = (B * S) // G
-    xg = _constrain(x.reshape(G, Tg, D), rules, "batch", None, None)
+        # Switch-style aux load-balance loss over all E router outputs
+        density = jnp.mean(jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32),
+                           axis=0)
+        aux = E * jnp.sum(density * jnp.mean(probs, axis=0))
 
-    logits = jnp.einsum("gtd,de->gte", xg, p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, K)  # (G, Tg, K)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        # rows (token, choice) by held expert; the rest sort last
+        local = top_e.reshape(-1) - first
+        mine = (local >= 0) & (local < held)
+        if valid is not None:
+            mine &= jnp.repeat(valid.reshape(T), K)
+        group = jnp.where(mine, local, held)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1).astype(jnp.int32)
+        rows = jnp.take(jnp.repeat(xt, K, axis=0), order, axis=0,
+                        unique_indices=True)  # (T*K, D)
 
-    # Switch-style aux load-balance loss (per group, then averaged)
-    density = jnp.mean(jax.nn.one_hot(top_e[..., 0], E, dtype=jnp.float32),
-                       axis=1)  # (G, E)
-    mean_prob = jnp.mean(probs, axis=1)
-    aux = E * jnp.mean(jnp.sum(density * mean_prob, axis=-1))
+        # the expert weights come whole, (L, held, ...), with the layer
+        # index under "layer" (see `model._scan_layers`), or as one layer's
+        def matmul(lhs, w):
+            if "layer" not in p:
+                w = w[None]
+            return gm.expert_matmul(lhs, w, sizes, p.get("layer", 0),
+                                    interpret=interpret_mode())
 
-    cap_f = capacity_factor or cfg.moe_capacity_factor
-    cap = max(int(cap_f * K * Tg / E), 4)
+        h = matmul(rows, p["gate"])
+        u = matmul(rows, p["up"])
+        y = matmul(jax.nn.silu(h) * u, p["down"])  # (T*K, D), 0 past held
 
-    def dispatch_group(xt, te, tw):
-        """xt: (Tg, D); te/tw: (Tg, K) -> (out (Tg, D))."""
-        flat_e = te.reshape(-1)  # (Tg*K,)
-        onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-        pos = jnp.cumsum(onehot, axis=0) - 1
-        slot = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-        keep = slot < cap
-        tok_idx = jnp.repeat(jnp.arange(Tg), K)
-        e_idx = jnp.where(keep, flat_e, E)  # dummy row E for drops
-        c_idx = jnp.where(keep, slot, 0)
-        buf = jnp.full((E + 1, cap), -1, jnp.int32)
-        buf = buf.at[e_idx, c_idx].set(tok_idx)[:E]  # (E, cap)
-        gathered = jnp.take(xt, buf.clip(0), axis=0)  # (E, cap, D)
-        gathered = jnp.where((buf >= 0)[..., None], gathered, 0)
-        wbuf = jnp.zeros((E + 1, cap), jnp.float32)
-        wbuf = wbuf.at[e_idx, c_idx].add(
-            jnp.where(keep, tw.reshape(-1), 0.0))
-        return buf, gathered, wbuf[:E]
+        # back to (token, choice) order, each row weighted by its gate
+        slot = jnp.zeros_like(order).at[order].set(  # sorted position of
+            jnp.arange(T * K, dtype=order.dtype))    # each (t, k) row
+        w = jnp.where(mine, top_w.reshape(-1), 0.0)
+        out = jnp.sum((jnp.take(y, slot, axis=0).astype(jnp.float32)
+                       * w[:, None]).reshape(T, K, D), axis=1)
 
-    buf, gathered, wbuf = jax.vmap(dispatch_group)(xg, top_e, top_w)
-    gathered = _constrain(gathered, rules, "batch", "expert", None, None)
+        if cfg.moe_dense_residual:
+            h = jax.nn.silu(jnp.einsum("td,df->tf", xt, p["res_gate"]))
+            h = h * jnp.einsum("td,df->tf", xt, p["res_up"])
+            out = out + jnp.einsum("tf,fd->td", h,
+                                   p["res_down"]).astype(out.dtype)
 
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", gathered, p["gate"]))
-    h = h * jnp.einsum("gecd,edf->gecf", gathered, p["up"])
-    eo = jnp.einsum("gecf,efd->gecd", h, p["down"])  # (G, E, cap, D)
-    eo = eo * wbuf[..., None].astype(eo.dtype)
-
-    def combine_group(eo_g, buf_g):
-        out = jnp.zeros((Tg, D), eo_g.dtype)
-        flat = eo_g.reshape(E * cap, D) * (buf_g.reshape(-1, 1) >= 0)
-        return out.at[buf_g.clip(0).reshape(-1)].add(flat)
-
-    out = _constrain(jax.vmap(combine_group)(eo, buf), rules,
-                     "batch", None, None)
-
-    if cfg.moe_dense_residual:
-        h = jax.nn.silu(jnp.einsum("gtd,df->gtf", xg, p["res_gate"]))
-        h = h * jnp.einsum("gtd,df->gtf", xg, p["res_up"])
-        out = out + jnp.einsum("gtf,fd->gtd", h, p["res_down"]).astype(out.dtype)
-
-    return out.reshape(B, S, D).astype(x.dtype), aux
+        stats = jnp.stack([sizes[:held].sum(),
+                           gm.rows_computed(sizes, T * K),
+                           jnp.sum(sizes[:held] > 0)]).astype(jnp.int32)
+    return out.reshape(B, S, D).astype(x.dtype), aux, stats

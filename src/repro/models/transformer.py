@@ -1,11 +1,17 @@
 """Block-composed transformer backbones for all assigned families.
 
-A model is embedding -> scan over homogeneous BLOCKS -> final norm -> tied
-logits.  A block bundles the family's repeating pattern so lax.scan sees one
-body (small HLO, FSDP all-gather per block):
+A model is embedding -> scan over homogeneous BLOCKS -> final norm -> logits
+(the embedding's, or a separate head).  A block bundles the family's
+repeating pattern so lax.scan sees one body (small HLO, FSDP all-gather per
+block):
 
   dense:   1 x (self-attn + swiglu)
-  moe:     1 x (self-attn + moe-ffn [+ dense residual])
+  moe:     1 x (self-attn + dropless moe-ffn [+ dense residual])
+
+A config with per-layer attention kinds (`layer_windows`: each layer's
+window, 0 = full attention with its own RoPE) keeps one block per layer;
+the model scans one period of them a step (`layer_kinds`,
+`model._scan_layers`).
   hybrid:  `attn_every` sub-layers: 1 attn + (attn_every-1) mamba, ffn
            alternating dense/moe per `moe_every`
   vlm:     (cross_attn_every-1) x (self+mlp) + 1 x (cross-attn+mlp)
@@ -17,7 +23,7 @@ Caches are pytrees stacked over blocks; scan maps over (params, cache).
 """
 from __future__ import annotations
 
-import functools
+import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -29,6 +35,25 @@ from . import attention as attn
 from . import moe as moe_mod
 from . import ssm
 from .layers import ParamDef, mlp_defs, rms_norm, swiglu
+
+
+def layer_kinds(cfg: ModelConfig, window: Optional[int] = None
+                ) -> Tuple[Tuple[ModelConfig, int], ...]:
+    """(config, window) of each layer of one period of the attention-kind
+    pattern.  A config with `layer_windows` gives each layer its own window
+    and RoPE (YaRN on the full-attention ones) and takes no `window`
+    override; any other config is one kind: `window`, else its
+    `sliding_window`, on every layer."""
+    if not cfg.layer_windows:
+        return ((cfg, cfg.sliding_window if window is None else window),)
+    if window is not None:
+        raise ValueError(f"{cfg.name} sets a window per layer "
+                         f"(layer_windows); a window override of {window} "
+                         f"would run one window on every layer")
+    return tuple(
+        (dataclasses.replace(cfg, layer_windows=(), sliding_window=w,
+                             rope_yarn=None if w else cfg.rope_yarn), w)
+        for w in cfg.layer_windows)
 
 
 def n_blocks(cfg: ModelConfig) -> int:
@@ -179,12 +204,18 @@ def cache_specs_for(cfg: ModelConfig, rules: AxisRules,
 # ---------------------------------------------------------------------------
 
 
+def _attn_scope(window: int):
+    return jax.named_scope("attn_window" if window else "attn_full")
+
+
 def _self_attn_full(cfg, p, x, positions, *, window, causal=True, q_block=512,
                     rules=None):
-    q, k, v = attn.qkv_project(cfg, p, x, positions, rules=rules)
-    ctx = attn.blocked_attention(cfg, q, k, v, causal=causal, window=window,
-                                 q_block=q_block, rules=rules)
-    return attn.attn_out(p, ctx, rules), k, v
+    with _attn_scope(window):
+        q, k, v = attn.qkv_project(cfg, p, x, positions, rules=rules)
+        ctx = attn.blocked_attention(cfg, q, k, v, causal=causal,
+                                     window=window, q_block=q_block,
+                                     rules=rules)
+        return attn.attn_out(p, ctx, rules), k, v
 
 
 def _write_cache(cache_k, cache_v, k, v, pos, ring: bool):
@@ -222,11 +253,12 @@ def _self_attn_decode(cfg, p, x, pos, k_pos, cache_k, cache_v, *, window,
     else:
         positions = jnp.full((B, 1), pos, jnp.int32)
         q_pos = pos
-    q, k, v = attn.qkv_project(cfg, p, x, positions, rules=rules)
-    cache_k, cache_v = _write_cache(cache_k, cache_v, k, v, pos, ring)
-    ctx = attn.decode_attention(cfg, q, cache_k, cache_v, q_pos, k_pos,
-                                window=window)
-    return attn.attn_out(p, ctx, rules), cache_k, cache_v
+    with _attn_scope(window):
+        q, k, v = attn.qkv_project(cfg, p, x, positions, rules=rules)
+        cache_k, cache_v = _write_cache(cache_k, cache_v, k, v, pos, ring)
+        ctx = attn.decode_attention(cfg, q, cache_k, cache_v, q_pos, k_pos,
+                                    window=window)
+        return attn.attn_out(p, ctx, rules), cache_k, cache_v
 
 
 def _cross_attn(cfg, p, x, xk, xv, rules=None):
@@ -250,13 +282,17 @@ def cross_kv(cfg, p, memory):
 
 def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
                 window=0, cache=None, memory=None, rules: AxisRules = None,
-                causal=True, q_block=512):
+                causal=True, q_block=512, valid=None):
     """Full-sequence block application (train + prefill).
 
-    Returns (x, new_cache, aux_loss).
+    valid: optional (B, S) bool marking real tokens (an MoE layer computes
+    no expert rows for the others).
+
+    Returns (x, new_cache, aux_loss, moe stats (moe blocks; else None)).
     """
     bt = block_type or cfg.family
     aux = jnp.float32(0.0)
+    stats = None
 
     if bt in ("dense", "moe"):
         h, k, v = _self_attn_full(cfg, bp["attn"], rms_norm(x, bp["ln1"]),
@@ -265,12 +301,12 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
         x = x + h
         h2 = rms_norm(x, bp["ln2"])
         if bt == "moe":
-            f, aux = moe_mod.moe_ffn(cfg, bp["moe"], h2, rules)
+            f, aux, stats = moe_mod.moe_ffn(cfg, bp["moe"], h2, valid=valid)
         else:
             f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"], rules)
         x = x + f
         new_cache = None if cache is None else dict(cache, k=k, v=v)
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     if bt == "hybrid":
         k_sub = cfg.attn_every
@@ -298,7 +334,7 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
             h2 = rms_norm(x, bp["ln_ffn"][i])
             if cfg.num_experts and i % cfg.moe_every == 1:
                 mo = jax.tree.map(lambda a: a[n_moe_used], bp["moe"])
-                f, a = moe_mod.moe_ffn(cfg, mo, h2, rules)
+                f, a, _ = moe_mod.moe_ffn(cfg, mo, h2, valid=valid)
                 aux = aux + a
                 n_moe_used += 1
             else:
@@ -309,7 +345,7 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
         if new_cache is not None:
             new_cache["conv"] = jnp.stack(convs)
             new_cache["h"] = jnp.stack(hs)
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     if bt == "vlm":
         k_sub = cfg.cross_attn_every
@@ -340,7 +376,7 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
         if new_cache is not None:
             new_cache["k"] = jnp.stack(ks)
             new_cache["v"] = jnp.stack(vs)
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     if bt == "xdec":
         h, k, v = _self_attn_full(cfg, bp["self"], rms_norm(x, bp["ln1"]),
@@ -356,7 +392,7 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
         ml = bp["mlp"]
         x = x + swiglu(rms_norm(x, bp["ln2"]), ml["gate"], ml["up"], ml["down"], rules)
         new_cache = None if cache is None else dict(cache, k=k, v=v, xk=xk, xv=xv)
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     if bt == "ssm":
         h, shift_a, wkv = ssm.rwkv_time_mix(
@@ -374,7 +410,7 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
         x = x + h2
         new_cache = None if cache is None else dict(
             cache, shift_a=shift_a, shift_f=shift_f, wkv=wkv)
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     raise ValueError(f"unknown block type {bt}")
 
@@ -405,14 +441,34 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
     New-token K/V rows scatter into exactly the owning pages (O(new tokens)
     writes — no pool-wide copy); attention gathers K/V through the table so
     only the P pages the table names are ever read.  dense/moe only.
+
+    Returns (x, new cache, moe stats (moe; else None)).
     """
     bt = cfg.family
     if bt not in ("dense", "moe"):
         raise NotImplementedError(f"paged decode supports dense/moe; got {bt!r}")
+    with _attn_scope(window):
+        x, ck, cv = _paged_attn(cfg, bp, x, q_pos, table, lengths, cache,
+                                window=window, rules=rules, impl=impl,
+                                cow=cow)
+    h2 = rms_norm(x, bp["ln2"])
+    stats = None
+    if bt == "moe":
+        f, _, stats = moe_mod.moe_ffn(cfg, bp["moe"], h2,
+                                      valid=q_pos < lengths[:, None])
+    else:
+        f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"],
+                   rules)
+    return x + f, dict(cache, k=ck, v=cv), stats
+
+
+def _paged_attn(cfg, bp, x, q_pos, table, lengths, cache, *, window, rules,
+                impl, cow):
+    """The attention half of `block_decode_paged`: (x + attention, new k
+    pool, new v pool)."""
     B, Q, _ = x.shape
     ps = cache["k"].shape[1]
     P = table.shape[1]
-
     h_in = rms_norm(x, bp["ln1"])
     q, k, v = attn.qkv_project(cfg, bp["attn"], h_in, q_pos, rules=rules)
 
@@ -458,14 +514,7 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
         k_pos = attn.paged_k_pos(lengths, P * ps)
         ctx = attn.decode_attention(cfg, q, kseq, vseq, q_pos, k_pos,
                                     window=window)
-    x = x + attn.attn_out(bp["attn"], ctx, rules)
-    h2 = rms_norm(x, bp["ln2"])
-    if bt == "moe":
-        f, _ = moe_mod.moe_ffn(cfg, bp["moe"], h2, rules)
-    else:
-        f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"],
-                   rules)
-    return x + f, dict(cache, k=ck, v=cv)
+    return x + attn.attn_out(bp["attn"], ctx, rules), ck, cv
 
 
 # ---------------------------------------------------------------------------
@@ -492,22 +541,24 @@ def block_verify(cfg: ModelConfig, bp, x, q_pos, valid, k_pos, cache, *,
     B, Q, _ = x.shape
     W = cache["k"].shape[1]
 
-    h_in = rms_norm(x, bp["ln1"])
-    q, k, v = attn.qkv_project(cfg, bp["attn"], h_in, q_pos, rules=rules)
-    # scatter the span's K/V rows at their absolute positions; invalid
-    # rows index out of bounds and are dropped (no null row in the flat
-    # layout, so masked writes must not land anywhere)
-    rows = jnp.arange(B)[:, None]
-    # out-of-range valid positions drop too (fail-safe, never clamp onto
-    # the newest live row)
-    idx = jnp.where(valid, q_pos, W)
-    ck = cache["k"].at[rows, idx].set(k, mode="drop")
-    cv = cache["v"].at[rows, idx].set(v, mode="drop")
-    ctx = attn.decode_attention(cfg, q, ck, cv, q_pos, k_pos, window=window)
-    x = x + attn.attn_out(bp["attn"], ctx, rules)
+    with _attn_scope(window):
+        h_in = rms_norm(x, bp["ln1"])
+        q, k, v = attn.qkv_project(cfg, bp["attn"], h_in, q_pos, rules=rules)
+        # scatter the span's K/V rows at their absolute positions; invalid
+        # rows index out of bounds and are dropped (no null row in the flat
+        # layout, so masked writes must not land anywhere)
+        rows = jnp.arange(B)[:, None]
+        # out-of-range valid positions drop too (fail-safe, never clamp
+        # onto the newest live row)
+        idx = jnp.where(valid, q_pos, W)
+        ck = cache["k"].at[rows, idx].set(k, mode="drop")
+        cv = cache["v"].at[rows, idx].set(v, mode="drop")
+        ctx = attn.decode_attention(cfg, q, ck, cv, q_pos, k_pos,
+                                    window=window)
+        x = x + attn.attn_out(bp["attn"], ctx, rules)
     h2 = rms_norm(x, bp["ln2"])
     if bt == "moe":
-        f, _ = moe_mod.moe_ffn(cfg, bp["moe"], h2, rules)
+        f, _, _ = moe_mod.moe_ffn(cfg, bp["moe"], h2, valid=valid)
     else:
         f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"],
                    rules)
@@ -533,7 +584,7 @@ def block_decode(cfg: ModelConfig, bp, x, pos, k_pos, cache, *,
         x = x + h
         h2 = rms_norm(x, bp["ln2"])
         if bt == "moe":
-            f, _ = moe_mod.moe_ffn(cfg, bp["moe"], h2, rules)
+            f, _, _ = moe_mod.moe_ffn(cfg, bp["moe"], h2)
         else:
             f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"], rules)
         return x + f, new_cache
@@ -561,7 +612,7 @@ def block_decode(cfg: ModelConfig, bp, x, pos, k_pos, cache, *,
             h2 = rms_norm(x, bp["ln_ffn"][i])
             if cfg.num_experts and i % cfg.moe_every == 1:
                 mo = jax.tree.map(lambda a: a[n_moe_used], bp["moe"])
-                f, _ = moe_mod.moe_ffn(cfg, mo, h2, rules)
+                f, _, _ = moe_mod.moe_ffn(cfg, mo, h2)
                 n_moe_used += 1
             else:
                 ml = jax.tree.map(lambda a: a[n_dense_used], bp["mlp"])
@@ -609,7 +660,7 @@ def block_decode(cfg: ModelConfig, bp, x, pos, k_pos, cache, *,
         return x, new_cache
 
     if bt == "ssm":
-        x, new_cache, _ = block_apply(cfg, bp, x,
+        x, new_cache, _, _ = block_apply(cfg, bp, x,
                                       jnp.full((x.shape[0], 1), pos, jnp.int32),
                                       block_type="ssm", cache=cache, rules=rules)
         return x, new_cache

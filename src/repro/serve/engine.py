@@ -66,6 +66,8 @@ from ..configs.base import ModelConfig
 from ..faults import FaultEvent, FaultInjector
 from ..launch.mesh import data_mesh
 from ..models import model as M
+from ..models import moe as moe_mod
+from ..models.transformer import layer_kinds
 from ..obs import MetricsRegistry, NULL_TRACER, SLOTracker, Tracer, meets_slo
 from ..sharding import AxisRules
 from .memory import KVMemoryManager
@@ -112,6 +114,17 @@ class TickRecord:
     brownout_level: int = 0  # degradation-ladder level this tick (0 = full)
     meta_transfers: int = 0  # packed host->device metadata transfers
     prefill_wait_s: float = 0.0  # host blocked on prefill results (seconds)
+    # MoE layers, summed over layers, over the tick's paged decode step and
+    # whole-prompt prefill dispatches (read back with their tokens):
+    moe_rows_routed: int = 0  # (token, expert) rows routed to held experts
+    moe_rows_computed: int = 0  # rows the grouped kernel ran, tile padding in
+    moe_experts_touched: int = 0  # held experts with at least one row
+    moe_decode_rows_routed: int = 0  # ... of the decode step alone
+    moe_decode_experts_touched: int = 0
+    # keys the paged decode step's queries must attend, summed over layers:
+    # window layers (at most their window each) and full-attention layers
+    attn_keys_window: int = 0
+    attn_keys_full: int = 0
 
 
 @dataclasses.dataclass
@@ -203,6 +216,13 @@ class ServeMetrics:
             "serve.shed_requests": "shed",
             "serve.crashes": "crashes",
             "serve.meta_transfers": "meta_transfers",
+            "serve.moe_rows_routed": "moe_rows_routed",
+            "serve.moe_rows_computed": "moe_rows_computed",
+            "serve.moe_experts_touched": "moe_experts_touched",
+            "serve.moe_decode_rows_routed": "moe_decode_rows_routed",
+            "serve.moe_decode_experts_touched": "moe_decode_experts_touched",
+            "serve.attn_keys_window": "attn_keys_window",
+            "serve.attn_keys_full": "attn_keys_full",
         }
         for metric, field in per_tick.items():
             reg.counter(metric).inc(
@@ -576,6 +596,15 @@ class ServeEngine:
         self._meta = _MetaPacker()
         self._tick_meta = 0  # packed metadata transfers this tick
         self._tick_prefill_wait = 0.0  # seconds blocked on prefill results
+        # MoE counters ride at the tail of the decode / prefill token
+        # outputs (the per-tick device-to-host read that exists anyway)
+        self._expert_stats = cfg.family == "moe" and bool(cfg.num_experts)
+        self._tick_moe = np.zeros(5, np.int64)
+        # window of every layer (0 = full attention), for the key counters
+        kinds = [w for _, w in layer_kinds(cfg)]
+        self._layer_windows = np.resize(np.asarray(kinds, np.int64),
+                                        cfg.num_layers)
+        self._tick_keys = np.zeros(2, np.int64)
         # block-table image staged in the previous tick's overlap window;
         # consumed (or discarded on any page/membership change) at bind
         self._plan: Optional[Dict[str, Any]] = None
@@ -667,6 +696,7 @@ class ServeEngine:
             # without prefix sharing no page can ever reach refcount 2, so
             # the fused COW copy is dead work — trace it out entirely
             use_cow = self.prefix_share
+            stats = self._expert_stats
 
             def unpack(meta, q):
                 w = meta.shape[0] // cap - q - 4
@@ -680,11 +710,14 @@ class ServeEngine:
 
             def decode(params, blocks, meta):
                 tok, pos, table, lengths, cow_src, cow_dst = unpack(meta, 1)
-                logits, new_cache = M.paged_decode_step(
+                logits, new_cache, *moe = M.paged_decode_step(
                     cfg, params, {"blocks": blocks}, tok, pos, table,
                     lengths, rules=rules, impl=impl,
-                    cow=(cow_src, cow_dst) if use_cow else None)
+                    cow=(cow_src, cow_dst) if use_cow else None,
+                    stats=stats)
                 nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+                if moe:  # the counters ride at the tail
+                    nxt = jnp.concatenate([nxt, *moe])
                 return nxt, new_cache["blocks"]
 
             def verify(params, blocks, meta, q):
@@ -821,16 +854,19 @@ class ServeEngine:
         km = self._k_mesh(self.k)
         cfg, rules, cache_len = self.cfg, self.rules, self.cache_len
         paged = self.kv_layout == "paged"
+        stats = self._expert_stats
 
         def build():
             def prefill(params, tokens, true_len):
                 # paged rows stay at bucket length (chopped into pages by
                 # the insert scatter); flat rows pad out to cache_len
-                logits, cache = M.prefill(
+                logits, cache, *moe = M.prefill(
                     cfg, params, tokens, rules=rules, remat=False,
                     cache_len=bucket if paged else cache_len,
-                    true_len=true_len)
+                    true_len=true_len, stats=stats)
                 nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+                if moe:  # the counters ride at the tail
+                    nxt = jnp.concatenate([nxt, *moe])
                 if paged:
                     return nxt, cache["blocks"]["k"], cache["blocks"]["v"]
                 return nxt, cache["blocks"], cache["k_pos"]
@@ -1349,13 +1385,14 @@ class ServeEngine:
                                  k_pos_rows)
                 nbytes += self._pool_bytes  # at[].set rebuilds the pool
             if defer is not None:
-                defer.append((nxt, list(enumerate(group))))
+                defer.append((nxt, list(enumerate(group)), True))
                 continue
             # settle at prefill's OWN sync point (first token AND the
             # insert scatter), so prefill device time lands on the prefill
             # track instead of inside the next decode's device_wait
             self._prefill_wait((nxt, self.blocks))
             nxt = np.asarray(nxt)
+            self._count_moe(nxt)
             now = self._now()
             for i, r in enumerate(group):
                 self._start_decoding(r, int(nxt[i]), now)
@@ -1428,7 +1465,7 @@ class ServeEngine:
                 else:
                     self._prefilling[slot] = (req, end)
             if done_group:
-                defer.append((nxt, done_group))
+                defer.append((nxt, done_group, False))
         for slot in finished:
             del self._prefilling[slot]
         return n_chunks, nbytes, n_dispatch
@@ -1646,6 +1683,7 @@ class ServeEngine:
             if self.kv_layout == "paged":
                 table, lengths, cow_src, cow_dst = self._paged_batch_inputs(
                     active, np.ones(self.capacity, np.int32))
+                self._count_keys(lengths)
                 nxt, self.blocks = decode_fn(
                     self.params, self.blocks,
                     self._pack_meta(self.next_tok,
@@ -1663,6 +1701,8 @@ class ServeEngine:
                 jax.block_until_ready(nxt)
             nxt_np = np.asarray(nxt)
             t_step = time.perf_counter() - t0
+            if self.kv_layout == "paged":
+                self._count_moe(nxt_np, decode=True)
             sched.end_iteration()
             now = self._now()
             emitted = 0
@@ -1679,6 +1719,27 @@ class ServeEngine:
 
         return settle
 
+    def _count_moe(self, out: np.ndarray, decode: bool = False) -> None:
+        """Add the MoE counters at the tail of a decode or whole-prompt
+        prefill output to this tick's (nothing for a model without
+        them)."""
+        if not self._expert_stats:
+            return
+        routed, computed, touched = out[-moe_mod.N_STATS:]
+        self._tick_moe[:3] += (routed, computed, touched)
+        if decode:
+            self._tick_moe[3:] += (routed, touched)
+
+    def _count_keys(self, lengths: np.ndarray) -> None:
+        """Keys this decode step's queries must attend, from the live
+        lengths: min(length, window) on each window layer, the length on
+        each full-attention layer."""
+        live = np.asarray(lengths, np.int64)
+        live = live[live > 0]
+        w = self._layer_windows
+        self._tick_keys[0] += int(np.minimum(live[:, None], w[w > 0]).sum())
+        self._tick_keys[1] += int(live.sum()) * int((w == 0).sum())
+
     def _finish_at_capacity(self) -> None:
         """A slot whose next write position is past the cache can't store
         another KV row: finish its request instead of silently overwriting
@@ -1694,13 +1755,18 @@ class ServeEngine:
         """Finish the prefill dispatches the overlap window deferred: ONE
         block covering every outstanding first-token handle plus the KV
         pool's latest handle, then the PREFILL -> DECODING transitions in
-        dispatch order (same order the synchronous path runs them)."""
+        dispatch order (same order the synchronous path runs them).  Each
+        entry is (handle, [(row, request), ...], whether the handle carries
+        the MoE counters at its tail: whole-prompt prefills do, chunks do
+        not)."""
         if not pending:
             return
-        self._prefill_wait(([h for h, _ in pending], self.blocks))
+        self._prefill_wait(([h for h, _, _ in pending], self.blocks))
         now = self._now()
-        for handle, group in pending:
+        for handle, group, counted in pending:
             nxt_np = np.asarray(handle)
+            if counted:
+                self._count_moe(nxt_np)
             for i, req in group:
                 self._start_decoding(req, int(nxt_np[i]), now)
 
@@ -1833,6 +1899,8 @@ class ServeEngine:
         tick_t0 = time.perf_counter() if trc.enabled else 0.0
         self._tick_meta = 0  # packed host->device transfers this tick
         self._tick_prefill_wait = 0.0  # seconds blocked on prefill results
+        self._tick_moe[:] = 0
+        self._tick_keys[:] = 0
 
         # ---- fault phase: injected faults land BEFORE the scheduler so a
         # crash on the same tick as a scale event has a fixed, replayable
@@ -2020,6 +2088,13 @@ class ServeEngine:
                          brownout_level=(self.ladder.level
                                          if self.ladder is not None else 0),
                          meta_transfers=self._tick_meta,
+                         moe_rows_routed=int(self._tick_moe[0]),
+                         moe_rows_computed=int(self._tick_moe[1]),
+                         moe_experts_touched=int(self._tick_moe[2]),
+                         moe_decode_rows_routed=int(self._tick_moe[3]),
+                         moe_decode_experts_touched=int(self._tick_moe[4]),
+                         attn_keys_window=int(self._tick_keys[0]),
+                         attn_keys_full=int(self._tick_keys[1]),
                          prefill_wait_s=self._tick_prefill_wait,
                          **kv)
         self._tick_faults = {"crashes": 0, "retries": 0, "shed": 0}

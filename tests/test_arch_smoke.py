@@ -42,7 +42,8 @@ def mesh_rules():
 @pytest.mark.parametrize("arch", ARCHS)
 def test_reduced_variant_is_reduced(arch):
     cfg = smoke_variant(get_config(arch))
-    assert cfg.num_layers <= 2 or (cfg.family in ("hybrid", "vlm"))
+    assert cfg.num_layers <= max(2, cfg.period()) or (
+        cfg.family in ("hybrid", "vlm"))
     assert cfg.d_model <= 512
     assert cfg.num_experts <= 4
 

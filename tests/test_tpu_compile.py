@@ -1,4 +1,5 @@
-"""Compile the paged-attention Pallas kernel for a described TPU v5e.
+"""Compile the Pallas kernels (paged attention, the grouped expert matmul)
+for a described TPU v5e.
 
 Interpret mode never checks the TPU compiler's block-shape tiling rules, so
 these tests compile the kernel (interpret=False) for a v5e chip that is
@@ -78,3 +79,35 @@ def test_paged_attention_compiles_for_v5e(name, one_chip,
                         sds((B, P), jnp.int32), sds((B,), jnp.int32)
                         ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+_MELLUM = get_config("mellum2-12b-a2.5b")
+
+# (rows, k, n): mellum-serve-long's decode step (16 slots x top-8) and a
+# 3,584-token prefill, through gate/up (2304 -> 896) and down (896 -> 2304)
+_EXPERT_SHAPES = {
+    "mellum_decode_gate": (16 * 8, _MELLUM.d_model, _MELLUM.d_ff),
+    "mellum_decode_down": (16 * 8, _MELLUM.d_ff, _MELLUM.d_model),
+    "mellum_prefill_gate": (3584 * 8, _MELLUM.d_model, _MELLUM.d_ff),
+    "mellum_prefill_down": (3584 * 8, _MELLUM.d_ff, _MELLUM.d_model),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_EXPERT_SHAPES))
+def test_expert_matmul_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    from repro.kernels.grouped_matmul import expert_matmul
+
+    m, k, n = _EXPERT_SHAPES[name]
+    L, (_, G) = _MELLUM.num_layers, _MELLUM.held_experts()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda r, w, s, l: expert_matmul(r, w, s, l,
+                                                  interpret=False))
+    compiled = fn.lower(sds((m, k), jnp.bfloat16),
+                        sds((L, G, k, n), jnp.bfloat16),
+                        sds((G + 1,), jnp.int32), sds((), jnp.int32)
+                        ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%expert_matmul" in text
