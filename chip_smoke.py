@@ -224,8 +224,11 @@ def kernel_phase(clock: CompileClock) -> None:
     for Q in (1, 5):
         mark = clock.mark()
         q = jnp.asarray(rng.standard_normal((B, KV, Q * G, hd)), jnp.bfloat16)
-        kp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.bfloat16)
-        vp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.bfloat16)
+        # a 2-layer stack of pages, layer 1 read
+        kp = jnp.asarray(rng.standard_normal((2, N, ps, KV * hd)),
+                         jnp.bfloat16)
+        vp = jnp.asarray(rng.standard_normal((2, N, ps, KV * hd)),
+                         jnp.bfloat16)
         lengths = rng.integers(Q, P * ps + 1, size=B).astype(np.int32)
         perm = rng.permutation(np.arange(1, N))  # pages shuffled
         table = np.full((B, P), -1, np.int32)
@@ -234,11 +237,11 @@ def kernel_phase(clock: CompileClock) -> None:
             n = -(-int(lengths[b]) // ps)
             table[b, :n] = perm[used:used + n]
             used += n
-        out = paged_attention(q, kp, vp, jnp.asarray(table),
+        out = paged_attention(q, kp, vp, 1, jnp.asarray(table),
                               jnp.asarray(lengths), q_span=Q,
                               interpret=interpret)
         with jax.default_matmul_precision("highest"):
-            want = ref.paged_attention_ref(q, kp, vp, jnp.asarray(table),
+            want = ref.paged_attention_ref(q, kp, vp, 1, jnp.asarray(table),
                                            jnp.asarray(lengths), q_span=Q)
         got = np.asarray(out.astype(jnp.float32))
         want = np.asarray(want.astype(jnp.float32))

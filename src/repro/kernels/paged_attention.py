@@ -3,46 +3,55 @@
 One QUERY SPAN per sequence (Q=1 plain decode; Q=k+1 speculative
 verification, where the span is [current token, k draft tokens]; Q=chunk
 for a chunked-prefill chunk) attends a KV cache scattered across
-fixed-size physical pages of one pool, `(N, page_size, KV, hd)`, which
-stays in HBM.
+fixed-size physical pages of one pool, which stays in HBM.
 
-Blocks of pages.  The grid is (B, KV / H, ceil(P / ppb)): step (b, g, j)
-covers kv heads g*H .. g*H + H - 1 of sequence b against its logical pages
-j*ppb .. j*ppb + ppb - 1.  The pool is passed as ppb K operands and ppb V
-operands (the same arrays; XLA adds no copy), each with a one-page
-BlockSpec `(1, page_size, KV, hd)`, and the Pallas pipeline double-buffers
-every operand: the next block's page DMAs run while the current block
-computes.  Which page operand i fetches at block j is computed once per
-call, outside the kernel, by `_fetch_plan` and SCALAR-PREFETCHED
-(`pltpu.PrefetchScalarGridSpec`) with the lengths and query starts, so an
-index map is one SMEM load; scalar work per page DMA is what bounded the
-page-at-a-time kernel.  Past a sequence's live length an operand repeats
-the page it held at the previous step and the pipeline starts no DMA: a
-block wholly past the live length fetches nothing and skips its compute,
-and the dead tail of a partial last block costs no DMA and is masked.  The
-kernel never reads a page the sequence does not own; per-sequence work is
-O(live tokens), not O(table width).
+The pool.  Every layer's pages sit in one stack `(L, N, page_size,
+KV*hd)`: a page is `page_size` token rows of all KV heads side by side,
+`KV*hd` lanes (head-major), which a TPU lays out unpadded and row-major
+for both head widths in use (8 x 80 = 5 x 128 lanes, 4 x 128).  The
+kernel reads layer `layer`'s pages in place, the layer index
+scalar-prefetched, so the model's layer scan hands it the whole pool and
+never slices, relayouts or restacks it.
 
-Compute per block, all H heads in one contraction.  A page block
-`(page_size, KV, hd)` is read as `(page_size*KV, hd)` rows (token-major,
-head-minor), the pool's own tiled layout, so no head is sliced out of it.
-The H*Q*G query rows score against every row of the block in one
-`(H*Q*G, ppb*page_size*KV)` MXU contraction (q.k in the pool's dtype, f32
-accumulation); a row's scores against another head's keys are masked with
-the causal / sliding-window / live-tail positions, so each query row
-softmaxes over its own head's keys only.  Scores, the online softmax
-(acc/m/l in VMEM scratch across blocks) and P.V stay in float32: for a
-bfloat16 pool, P is split exactly into three bfloat16 terms
-(P = p1 + p2 + p3, 24 significand bits) that share one pass of V through
-the MXU, so every product is exact and the sum accumulates in float32.
-Row r of a step's q is query position (r % (Q*G)) // G of kv head
-g*H + r // (Q*G), at absolute position q_start[b] + (r % (Q*G)) // G, each
-with its own mask, so verifying k drafts costs ONE sweep instead of k+1.
+Blocks of pages.  The grid is (B, R / RB, ceil(P / ppb)): step (b, r, j)
+covers query rows r*RB .. r*RB + RB - 1 of sequence b against its logical
+pages j*ppb .. j*ppb + ppb - 1.  The pool is passed as ppb K operands and
+ppb V operands (the same arrays; XLA adds no copy), each with a one-page
+BlockSpec `(None, None, page_size, KV*hd)` at `(layer, page, 0, 0)`, and
+the Pallas pipeline double-buffers every operand: the next block's page
+DMAs run while the current block computes.  Which page operand i fetches
+at block j is computed once per call, outside the kernel, by
+`_fetch_plan` and SCALAR-PREFETCHED (`pltpu.PrefetchScalarGridSpec`) with
+the layer, the lengths and the query starts, so an index map is one SMEM
+load; scalar work per page DMA is what bounded the page-at-a-time kernel.
+Past a sequence's live length an operand repeats the page it held at the
+previous step and the pipeline starts no DMA: a block wholly past the
+live length fetches nothing and skips its compute, and the dead tail of a
+partial last block costs no DMA and is masked.  The kernel never reads a
+page the sequence does not own; per-sequence work is O(live tokens), not
+O(table width).
 
-Block sizes.  `_block_sizes` picks (H, ppb) from the shapes alone: every
-kv head and `_BLOCK_TOKENS` tokens a block at decode widths; fewer tokens
-as the score tile grows with Q*G (verify spans, chunked prefill), and
-fewer heads a step only where one page's tile is already too large (long
+Compute per block, every kv head in one contraction.  Outside the kernel
+each query row is spread into its own kv head's `hd` lanes of a `KV*hd`
+row, zeros elsewhere; row h*Q*G + i is kv head h's row i (query position
+i // G).  A page block is `(ppb*page_size, KV*hd)` lane-dense token rows,
+so one `(RB, T) = q . k^T` MXU contraction over the lanes scores each row
+against its own head's keys only (the zeros take the other heads out) in
+the pool's dtype with float32 accumulation, and the softmax runs over the
+T tokens, masked by the causal / sliding-window / live-tail positions.
+The online softmax (acc/m/l in VMEM scratch across blocks) and P.V stay
+in float32: for a bfloat16 pool, P is split exactly into three bfloat16
+terms (P = p1 + p2 + p3, 24 significand bits) that share one pass of V
+through the MXU, so every product is exact and the sum accumulates in
+float32.  P.V gives `(RB, KV*hd)`; after the call each row keeps its own
+head's lanes.  A Q > 1 span masks each row at its own absolute position,
+so verifying k drafts costs ONE sweep instead of k+1.
+
+Block sizes.  `_block_sizes` picks (RB, ppb) from the shapes alone: every
+query row (all kv heads: `H*hd` lanes are 128-aligned only at H = KV when
+hd = 80) and `_BLOCK_TOKENS` tokens a block at decode widths; fewer tokens
+as the score tile grows with the rows (verify spans, chunked prefill), and
+fewer rows a step only where their accumulator is too large (long
 chunks); at least one page and at most the table width.
 Correctness is validated on CPU in interpret mode against
 kernels.ref.paged_attention_ref
@@ -62,24 +71,26 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 _BLOCK_TOKENS = 512           # tokens a block at decode widths
 _SCORE_TILE = 1 << 17         # float32 elements of one block's score tile
+_ACC_TILE = 1 << 18           # float32 elements of a step's accumulator
 
 
 def _live_pages(length, page_size: int):
     return (length + page_size - 1) // page_size
 
 
-def _block_sizes(page_size: int, kv_heads: int, rows: int,
+def _block_sizes(page_size: int, rows: int, lanes: int,
                  n_pages: int) -> tuple[int, int]:
-    """(kv heads, pages) a grid step covers.  Every kv head, unless even one
-    page's score tile (heads * rows, page_size * kv_heads) would pass
-    `_SCORE_TILE` elements (a long chunked-prefill span): then halve the
-    heads.  Pages: `_BLOCK_TOKENS` tokens, fewer where the tile would pass
-    `_SCORE_TILE`; at least 1 and at most the table width `n_pages`."""
-    heads = kv_heads
-    while heads % 2 == 0 and heads * rows * page_size * kv_heads > _SCORE_TILE:
-        heads //= 2
-    tokens = min(_BLOCK_TOKENS, _SCORE_TILE // (heads * rows * kv_heads))
-    return heads, max(1, min(tokens // page_size, n_pages))
+    """(query rows, pages) a grid step covers, of a sequence's `rows` rows
+    of `lanes` lanes.  Every row, unless their (rows, lanes) accumulator
+    would pass `_ACC_TILE` elements (a long chunked-prefill span): then
+    halve the rows while the half stays a multiple of 16.  Pages:
+    `_BLOCK_TOKENS` tokens, fewer where the (rows, tokens) score tile would
+    pass `_SCORE_TILE`; at least 1 and at most the table width `n_pages`."""
+    rb = rows
+    while rb % 32 == 0 and rb * lanes > _ACC_TILE:
+        rb //= 2
+    tokens = min(_BLOCK_TOKENS, _SCORE_TILE // rb)
+    return rb, max(1, min(tokens // page_size, n_pages))
 
 
 def _fetch_plan(block_table, lengths, page_size: int, ppb: int):
@@ -113,26 +124,24 @@ def _exact_parts(p, dtype):
     return parts
 
 
-def _paged_kernel(pages_ref, len_ref, qstart_ref, q_ref, *refs,
+def _paged_kernel(layer_ref, pages_ref, len_ref, qstart_ref, q_ref, *refs,
                   sm_scale: float, page_size: int, ppb: int, window: int,
-                  q_span: int):
+                  head_rows: int, group: int):
+    del layer_ref, pages_ref  # read by the index maps only
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * ppb:]
     b = pl.program_id(0)
-    g = pl.program_id(1)          # group of kv heads
+    r = pl.program_id(1)          # block of query rows
     j = pl.program_id(2)          # block of pages
-    H, QG, hd = q_ref.shape[1:]
-    KV = k_refs[0].shape[2]
-    G = QG // q_span
+    RB = q_ref.shape[0]
     T = ppb * page_size           # tokens a block
-    R, C = H * QG, T * KV         # score tile: (head, query row) x (token, kv)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[0] = jnp.zeros_like(o_ref[0])  # length-0 rows stay 0
+        o_ref[...] = jnp.zeros_like(o_ref)  # length-0 rows stay 0
 
     length = len_ref[b]
     n_blocks = (length + T - 1) // T
@@ -140,17 +149,13 @@ def _paged_kernel(pages_ref, len_ref, qstart_ref, q_ref, *refs,
     @pl.when(j < n_blocks)
     def _accumulate():
         dt = k_refs[0].dtype
-        k = jnp.concatenate([r[0].reshape(page_size * KV, hd)
-                             for r in k_refs], axis=0)   # (C, hd)
-        v = jnp.concatenate([r[0].reshape(page_size * KV, hd)
-                             for r in v_refs], axis=0)
-        q = q_ref[0].reshape(R, hd).astype(dt)
-        row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-        k_pos = j * T + col // KV
-        q_abs = qstart_ref[b] + (row % QG) // G
-        ok = ((g * H + row // QG == col % KV)          # own kv head
-              & (k_pos <= q_abs) & (k_pos < length))   # causal + live tail
+        k = jnp.concatenate([p[...] for p in k_refs], axis=0)  # (T, KV*hd)
+        v = jnp.concatenate([p[...] for p in v_refs], axis=0)
+        q = q_ref[...].astype(dt)                              # (RB, KV*hd)
+        row = r * RB + jax.lax.broadcasted_iota(jnp.int32, (RB, 1), 0)
+        k_pos = j * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        q_abs = qstart_ref[b] + (row % head_rows) // group
+        ok = (k_pos <= q_abs) & (k_pos < length)   # causal + live tail
         if window:  # sliding window from each query's own position
             ok &= (q_abs - k_pos) < window
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -163,7 +168,7 @@ def _paged_kernel(pages_ref, len_ref, qstart_ref, q_ref, *refs,
         parts = _exact_parts(probs, dt)
         pv = jax.lax.dot(jnp.concatenate(parts, axis=0), v,
                          preferred_element_type=jnp.float32)
-        pv = sum(pv[i * R:(i + 1) * R] for i in range(len(parts)))
+        pv = sum(pv[i * RB:(i + 1) * RB] for i in range(len(parts)))
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_cur
         l_ref[...] = l_prev * alpha + jnp.sum(probs, axis=1, keepdims=True)
@@ -172,20 +177,20 @@ def _paged_kernel(pages_ref, len_ref, qstart_ref, q_ref, *refs,
     def _done():
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe).reshape(H, QG, hd).astype(
-            o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "interpret", "q_span"))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_table: jax.Array, lengths: jax.Array, *,
-                    window: int = 0, interpret: bool = True,
-                    q_span: int = 1,
+                    layer: jax.Array, block_table: jax.Array,
+                    lengths: jax.Array, *, window: int = 0,
+                    interpret: bool = True, q_span: int = 1,
                     q_start: jax.Array | None = None) -> jax.Array:
     """q: (B, KV, q_span*G, hd) — `q_span` query positions per sequence, the
     G heads of each position packed contiguously (position-major rows);
-    k_pages/v_pages: (N, page_size, KV, hd);
+    k_pages/v_pages: (L, N, page_size, KV*hd) every layer's pages, of which
+    layer `layer` (int32 scalar) is read;
     block_table: (B, P) int32 physical page ids (-1 = absent);
     lengths: (B,) int32 live tokens INCLUDING the span's writes;
     q_start: (B,) int32 absolute position of each span's first query
@@ -195,56 +200,65 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     Returns (B, KV, q_span*G, hd).  Rows with length 0 return zeros.
     """
     B, KV, QG, hd = q.shape
-    N, page_size, KVp, hdp = k_pages.shape
-    assert (KV, hd) == (KVp, hdp) and v_pages.shape == k_pages.shape
+    _, _, page_size, lanes = k_pages.shape
+    assert lanes == KV * hd and v_pages.shape == k_pages.shape, (
+        q.shape, k_pages.shape, v_pages.shape)
     assert QG % q_span == 0, (QG, q_span)
     P = block_table.shape[1]
-    H, ppb = _block_sizes(page_size, KV, QG, P)
+    R = KV * QG
+    RB, ppb = _block_sizes(page_size, R, lanes, P)
     sm_scale = 1.0 / math.sqrt(hd)
     lengths = lengths.astype(jnp.int32)
     if q_start is None:
         q_start = lengths - q_span
 
+    # row h*QG + i holds q[:, h, i] in lanes h*hd .. h*hd + hd - 1
+    own = jnp.eye(KV, dtype=q.dtype)[None, :, None, :, None]
+    q_rows = (q[:, :, :, None, :] * own).reshape(B, R, lanes)
     pages = _fetch_plan(block_table, lengths, page_size, ppb)
 
     def kv_map(i):
-        return lambda b, g, j, pages, lens, qstart: (pages[b, j * ppb + i],
-                                                     0, 0, 0)
+        return lambda b, r, j, layer, pages, lens, qstart: (
+            layer[0], pages[b, j * ppb + i], 0, 0)
 
-    def q_map(b, g, j, pages, lens, qstart):
-        return (b, g, 0, 0)
+    def q_map(b, r, j, layer, pages, lens, qstart):
+        return (b, r, 0)
 
-    kv_specs = [pl.BlockSpec((1, page_size, KV, hd), kv_map(i))
+    kv_specs = [pl.BlockSpec((None, None, page_size, lanes), kv_map(i))
                 for i in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, KV // H, pl.cdiv(P, ppb)),
-        in_specs=[pl.BlockSpec((1, H, QG, hd), q_map)] + kv_specs * 2,
-        out_specs=pl.BlockSpec((1, H, QG, hd), q_map),
+        num_scalar_prefetch=4,
+        grid=(B, R // RB, pl.cdiv(P, ppb)),
+        in_specs=[pl.BlockSpec((None, RB, lanes), q_map)] + kv_specs * 2,
+        out_specs=pl.BlockSpec((None, RB, lanes), q_map),
         scratch_shapes=[
-            pltpu.VMEM((H * QG, hd), jnp.float32),
-            pltpu.VMEM((H * QG, 1), jnp.float32),
-            pltpu.VMEM((H * QG, 1), jnp.float32),
+            pltpu.VMEM((RB, lanes), jnp.float32),
+            pltpu.VMEM((RB, 1), jnp.float32),
+            pltpu.VMEM((RB, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, sm_scale=sm_scale,
                                page_size=page_size, ppb=ppb, window=window,
-                               q_span=q_span)
-    return pl.pallas_call(
+                               head_rows=QG, group=QG // q_span)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, QG, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, R, lanes), q.dtype),
         interpret=interpret,
-    )(pages, lengths, q_start.astype(jnp.int32), q,
-      *[k_pages] * ppb, *[v_pages] * ppb)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), pages, lengths,
+      q_start.astype(jnp.int32), q_rows, *[k_pages] * ppb, *[v_pages] * ppb)
+    # each row keeps its own head's lanes: (B, KV, QG, KV, hd) -> diagonal
+    out = jnp.diagonal(out.reshape(B, KV, QG, KV, hd), axis1=1, axis2=3)
+    return jnp.moveaxis(out, -1, 1)
 
 
 # (B, KV, G, hd, page_size, P, window, q_span[, lengths]): the first five
-# fit one block; the rest span three or more (`_block_sizes` gives 8
+# fit one block; the next five span three or more (`_block_sizes` gives 8
 # pages of 64 tokens), with lengths ending mid-block and on a block
 # boundary, a table width that is no multiple of the block, a sliding
 # window shorter than a block and Q > 1 spans that cross a block boundary;
-# the last, a chunk, takes one kv head a step
+# then a chunk (one page a step), danube's (8 x 80 lanes) and mellum's
+# (4 x 128) heads, and a chunk long enough to split its rows over steps
 SELFTEST_CASES = [
     (3, 2, 4, 32, 8, 4, 0, 1),
     (2, 1, 8, 64, 16, 3, 0, 1),
@@ -256,19 +270,23 @@ SELFTEST_CASES = [
     (3, 2, 2, 32, 64, 30, 0, 4, (0, 1026, 1537)),
     (2, 2, 2, 32, 64, 27, 150, 5, (514, 1700)),
     (2, 2, 4, 32, 64, 8, 0, 130, (0, 400)),
+    (3, 8, 4, 80, 16, 40, 0, 1, (0, 333, 640)),
+    (2, 4, 8, 128, 16, 40, 200, 1, (517, 640)),
+    (2, 2, 4, 64, 64, 12, 0, 520, (600, 0)),
 ]
 
 
-def random_case(case, rng, dtype=jnp.float32):
-    """Inputs for one case: q, pools, a block table over shuffled physical
-    pages and the lengths (random in [q_span, P*page_size] unless given)."""
+def random_case(case, rng, dtype=jnp.float32, layers=1):
+    """Inputs for one case: q, pools of `layers` layers, a block table over
+    shuffled physical pages and the lengths (random in [q_span, P*page_size]
+    unless given)."""
     import numpy as np
 
     B, KV, G, hd, ps, P, _, Q, *fixed = case
     N = B * P + 1
     q = jnp.asarray(rng.standard_normal((B, KV, Q * G, hd)), dtype)
-    kp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), dtype)
-    vp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), dtype)
+    kp = jnp.asarray(rng.standard_normal((layers, N, ps, KV * hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((layers, N, ps, KV * hd)), dtype)
     lengths = (np.asarray(fixed[0]) if fixed
                else rng.integers(Q, P * ps + 1, size=B))
     perm = rng.permutation(np.arange(1, N))  # pages deliberately shuffled
@@ -282,7 +300,8 @@ def random_case(case, rng, dtype=jnp.float32):
 
 
 def _selftest() -> None:
-    """Interpret-mode parity vs the pure-jnp oracle (CPU CI gate)."""
+    """Interpret-mode parity vs the pure-jnp oracle (CPU CI gate), reading
+    layer 1 of a 3-layer pool."""
     import numpy as np
 
     from . import ref
@@ -290,19 +309,20 @@ def _selftest() -> None:
     rng = np.random.default_rng(0)
     for case in SELFTEST_CASES:
         B, KV, G, hd, ps, P, win, Q, *fixed = case
-        q, kp, vp, table, lengths = random_case(case, rng)
+        q, kp, vp, table, lengths = random_case(case, rng, layers=3)
         if Q == 1 and not fixed:
             lengths = lengths.at[rng.integers(B)].set(0)  # an inactive row
-        out = paged_attention(q, kp, vp, table, lengths, window=win,
+        out = paged_attention(q, kp, vp, 1, table, lengths, window=win,
                               q_span=Q, interpret=True)
-        want = ref.paged_attention_ref(q, kp, vp, table, lengths,
+        want = ref.paged_attention_ref(q, kp, vp, 1, table, lengths,
                                        window=win, q_span=Q)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
-        H, ppb = _block_sizes(ps, KV, Q * G, P)
+        rb, ppb = _block_sizes(ps, KV * Q * G, KV * hd, P)
         print(f"paged_attention parity OK: B={B} KV={KV} G={G} hd={hd} "
-              f"ps={ps} P={P} blocks={-(-P // ppb)}x{ppb} heads={H} window={win} "
-              f"q_span={Q} lengths={np.asarray(lengths).tolist()}")
+              f"ps={ps} P={P} blocks={-(-P // ppb)}x{ppb} "
+              f"rows={KV * Q * G // rb}x{rb} window={win} q_span={Q} "
+              f"lengths={np.asarray(lengths).tolist()}")
 
 
 if __name__ == "__main__":

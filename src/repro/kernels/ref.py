@@ -33,14 +33,15 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                        block_table: jax.Array, lengths: jax.Array, *,
+                        layer, block_table: jax.Array, lengths: jax.Array, *,
                         window: int = 0, q_span: int = 1,
                         q_start: jax.Array | None = None) -> jax.Array:
     """Paged decode attention, gather-then-softmax oracle.
 
     q: (B, KV, q_span*G, hd) — `q_span` query positions per sequence in the
     grouped head layout (row j*G+g is query position j's head (kv, g));
-    k_pages/v_pages: (N, ps, KV, hd) physical page pools; block_table:
+    k_pages/v_pages: (L, N, ps, KV*hd) physical page pools of every layer,
+    head-major lanes, of which layer `layer` is read; block_table:
     (B, P) int32 physical page ids (-1 = absent, masked); lengths: (B,)
     int32 live tokens per sequence (including the span's own tokens);
     q_start: (B,) absolute position of each span's first query (default
@@ -49,13 +50,13 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """
     B, KV, QG, hd = q.shape
     G = QG // q_span
-    _, ps, _, _ = k_pages.shape
+    _, _, ps, _ = k_pages.shape
     P = block_table.shape[1]
     if q_start is None:
         q_start = lengths - q_span
     tbl = jnp.maximum(block_table, 0)
-    k = jnp.take(k_pages, tbl, axis=0).reshape(B, P * ps, KV, hd)
-    v = jnp.take(v_pages, tbl, axis=0).reshape(B, P * ps, KV, hd)
+    k = jnp.take(k_pages[layer], tbl, axis=0).reshape(B, P * ps, KV, hd)
+    v = jnp.take(v_pages[layer], tbl, axis=0).reshape(B, P * ps, KV, hd)
     scale = 1.0 / math.sqrt(hd)
     s = jnp.einsum("bkgh,bskh->bkgs", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale

@@ -262,14 +262,16 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
-    """(N, ps, KV, hd) pool + (B, P) table -> (B, P*ps, KV, hd) in logical
-    token order.  Absent entries (-1) clamp to the null page; callers mask
-    them by position validity (paged_k_pos)."""
+def gather_pages(pages: jax.Array, layer, block_table: jax.Array,
+                 kv_shape) -> jax.Array:
+    """Layer `layer` of the (L, N, ps, KV*hd) pools + (B, P) table ->
+    (B, P*ps, *kv_shape) in logical token order, gathered straight from
+    the stack (no layer slice is taken).  Absent entries (-1) clamp to the
+    null page; callers mask them by position validity (paged_k_pos)."""
     B, P = block_table.shape
-    _, ps, KV, hd = pages.shape
-    seq = jnp.take(pages, jnp.maximum(block_table, 0), axis=0)
-    return seq.reshape(B, P * ps, KV, hd)
+    ps = pages.shape[2]
+    seq = pages[layer, jnp.maximum(block_table, 0)]
+    return seq.reshape(B, P * ps, *kv_shape)
 
 
 def paged_k_pos(lengths: jax.Array, seq_len: int) -> jax.Array:

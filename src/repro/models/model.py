@@ -90,7 +90,7 @@ def _logits(cfg, params, x):
 
 
 def _scan_layers(cfg: ModelConfig, window: Optional[int], make_body, x,
-                 blocks, state=None):
+                 blocks, state=None, whole_state: bool = False):
     """Run the stacked layers over x.  `make_body(layer_cfg, window)` gives
     `body(x, (layer params, layer state)) -> (x, (new layer state,
     extras))`; `blocks`, `state` and the extras are stacked over layers
@@ -103,6 +103,12 @@ def _scan_layers(cfg: ModelConfig, window: Optional[int], make_body, x,
     layer's slice is updated in place.  An MoE layer's expert weights stay
     whole, with the layer's index beside them: the grouped kernel reads
     the layer's experts in place (a slice per step would copy them all).
+
+    whole_state: the body takes `(layer params, (whole state, layer
+    index))` and returns the whole new state, which rides in the carry of
+    a scan of one period a step (a period of one layer for one kind): the
+    state is never sliced per layer (the paged KV pools, which each layer
+    reads and writes by its index).
     """
     kinds = tfm.layer_kinds(cfg, window)
     experts = None
@@ -110,7 +116,7 @@ def _scan_layers(cfg: ModelConfig, window: Optional[int], make_body, x,
         moe = dict(blocks["moe"])
         experts = {k: moe.pop(k) for k in ("gate", "up", "down")}
         blocks = dict(blocks, moe=moe)
-    elif len(kinds) == 1:
+    elif len(kinds) == 1 and not whole_state:
         body = make_body(*kinds[0])
         if state is None:
             def body_nc(x, bp):
@@ -129,13 +135,19 @@ def _scan_layers(cfg: ModelConfig, window: Optional[int], make_body, x,
         exs = []
         for i, body in enumerate(bodies):
             layer = j * P + i
-            sl = None if st is None else jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), st)
+            if whole_state:
+                sl = (st, layer)
+            else:
+                sl = None if st is None else jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                           False), st)
             lp = jax.tree.map(lambda a: a[i], bp)
             if experts is not None:
                 lp = dict(lp, moe=dict(lp["moe"], layer=layer, **experts))
             x, (new_sl, ex) = body(x, (lp, sl))
-            if st is not None:
+            if whole_state:
+                st = new_sl
+            elif st is not None:
                 st = jax.tree.map(
                     lambda a, u: jax.lax.dynamic_update_index_in_dim(
                         a, u.astype(a.dtype), layer, 0), st, new_sl)
@@ -369,19 +381,31 @@ def decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int,
                      page_size: int) -> Dict[str, Any]:
-    """Physical K/V page pools: {"blocks": {"k","v": (nb, n_pages, ps, KV,
-    hd)}}.  Page 0 is the engine's reserved null page (masked writes land
-    there).  Per-slot fill depth lives in the block table + lengths the
-    caller threads through `paged_decode_step`; there is no device-side
-    k_pos state.  dense/moe only (flat recurrent state does not page)."""
+    """Physical K/V page pools: {"blocks": {"k","v": (nb, n_pages, ps,
+    KV*hd)}}, a token row's kv heads side by side (head-major lanes), the
+    layout the paged kernel reads and a TPU lays out unpadded.  Page 0 is
+    the engine's reserved null page (masked writes land there).  Per-slot
+    fill depth lives in the block table + lengths the caller threads
+    through `paged_decode_step`; there is no device-side k_pos state.
+    dense/moe only (flat recurrent state does not page)."""
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
             f"paged cache supports dense/moe; got {cfg.family!r}")
     nb = tfm.n_blocks(cfg)
-    kv, hd = cfg.kv_heads(), cfg.head_dim_()
     dt = jnp.dtype(cfg.dtype)
-    shape = (nb, n_pages, page_size, kv, hd)
+    shape = (nb, n_pages, page_size, cfg.kv_heads() * cfg.head_dim_())
     return {"blocks": {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}}
+
+
+def _cow_pages(blocks, cow):
+    """Copy-on-write share breaks: copy page `src` onto page `dst` in every
+    layer of both pools before the step's rows land in `dst` (the block
+    table already names it); rows without a break pass the null page for
+    both, which copies onto itself."""
+    if cow is None:
+        return blocks
+    src, dst = cow
+    return {k: a.at[:, dst].set(a[:, src]) for k, a in blocks.items()}
 
 
 def paged_decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
@@ -395,8 +419,8 @@ def paged_decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
     this one (0 = inactive row, output garbage, writes -> null page).
 
     cow: optional ((B,), (B,)) int32 (src, dst) page pairs — copy-on-write
-    share breaks fused into the scatter (see `transformer.block_decode_paged`;
-    rows without a break pass the null page for both).
+    share breaks fused into the step (`_cow_pages`; rows without a break
+    pass the null page for both).
 
     Returns (logits (B, 1, V), new cache), and with `stats` (MoE models)
     the (moe.N_STATS,) int32 expert counters summed over layers."""
@@ -406,21 +430,23 @@ def paged_decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
             x, rules.sharding("batch", None, None))
     q_pos = pos.astype(jnp.int32)[:, None]
     x, new_blocks, moe = _scan_layers(
-        cfg, window, _paged_body(q_pos, table, lengths, rules, impl, cow), x,
-        params["blocks"], cache["blocks"])
+        cfg, window, _paged_body(q_pos, table, lengths, rules, impl), x,
+        params["blocks"], _cow_pages(cache["blocks"], cow), whole_state=True)
     out = (_logits(cfg, params, x), dict(cache, blocks=new_blocks))
     return out + (_sum_stats(moe),) if stats else out
 
 
-def _paged_body(q_pos, table, lengths, rules, impl, cow=None):
-    """`make_body` for `_scan_layers` over `transformer.block_decode_paged`."""
+def _paged_body(q_pos, table, lengths, rules, impl):
+    """`make_body` for `_scan_layers(whole_state=True)` over
+    `transformer.block_decode_paged`: each layer gets the whole pools and
+    its index."""
     def make_body(lcfg, win):
         def body(x, xs):
-            bp, bc = xs
-            x, new_bc, moe = tfm.block_decode_paged(
-                lcfg, bp, x, q_pos, table, lengths, bc, window=win,
-                rules=rules, impl=impl, cow=cow)
-            return x, (new_bc, moe)
+            bp, (pools, layer) = xs
+            x, new_pools, moe = tfm.block_decode_paged(
+                lcfg, bp, x, q_pos, table, lengths, pools, layer,
+                window=win, rules=rules, impl=impl)
+            return x, (new_pools, moe)
         return body
     return make_body
 
@@ -451,7 +477,7 @@ def paged_prefill_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
              + jnp.arange(C, dtype=jnp.int32)[None])
     x, new_blocks, _ = _scan_layers(
         cfg, window, _paged_body(q_pos, table, chunk_end, rules, impl), x,
-        params["blocks"], cache["blocks"])
+        params["blocks"], cache["blocks"], whole_state=True)
     logits = _logits(cfg, params, x)
     last = jnp.clip(chunk_end - offset - 1, 0, C - 1).astype(jnp.int32)
     last_logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)
@@ -544,8 +570,8 @@ def paged_verify_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
             x, rules.sharding("batch", None, None))
     q_pos = pos.astype(jnp.int32)[:, None] + jnp.arange(Q, dtype=jnp.int32)
     x, new_blocks, _ = _scan_layers(
-        cfg, window, _paged_body(q_pos, table, lengths, rules, impl, cow), x,
-        params["blocks"], cache["blocks"])
+        cfg, window, _paged_body(q_pos, table, lengths, rules, impl), x,
+        params["blocks"], _cow_pages(cache["blocks"], cow), whole_state=True)
     return _logits(cfg, params, x), dict(cache, blocks=new_blocks)
 
 

@@ -421,22 +421,17 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
 
 
 def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
-                       *, window=0, rules: AxisRules = None, impl="xla",
-                       cow=None):
+                       layer, *, window=0, rules: AxisRules = None,
+                       impl="xla"):
     """Paged-KV block step over new tokens x: (B, Q, D) at positions
     q_pos: (B, Q).  Q == 1 is decode; Q > 1 is one chunked-prefill chunk.
 
-    cache: {"k": (N, ps, KV, hd), "v": ...} physical page pools shared by
-    every sequence; table: (B, P) int32 block table (-1 absent);
-    lengths: (B,) live tokens INCLUDING the new ones (0 = inactive row:
-    its writes route to the null page and its output is garbage).
-
-    cow: optional (src, dst) pair of (B,) int32 page ids for copy-on-write
-    share breaks: rows whose write position lands in a page shared with
-    another sequence have the page payload copied src -> dst BEFORE the new
-    rows scatter (the table already names dst), fused into this dispatch so
-    a break costs no extra launch.  Rows with no break use src == dst == 0
-    (the null page copies onto itself).
+    cache: {"k": (L, N, ps, KV*hd), "v": ...} every layer's physical page
+    pools, shared by every sequence, of which this block reads and writes
+    layer `layer` (int32 scalar) in place; table: (B, P) int32 block table
+    (-1 absent); lengths: (B,) live tokens INCLUDING the new ones
+    (0 = inactive row: its writes route to the null page and its output is
+    garbage).
 
     New-token K/V rows scatter into exactly the owning pages (O(new tokens)
     writes — no pool-wide copy); attention gathers K/V through the table so
@@ -449,8 +444,7 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
         raise NotImplementedError(f"paged decode supports dense/moe; got {bt!r}")
     with _attn_scope(window):
         x, ck, cv = _paged_attn(cfg, bp, x, q_pos, table, lengths, cache,
-                                window=window, rules=rules, impl=impl,
-                                cow=cow)
+                                layer, window=window, rules=rules, impl=impl)
     h2 = rms_norm(x, bp["ln2"])
     stats = None
     if bt == "moe":
@@ -462,32 +456,25 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
     return x + f, dict(cache, k=ck, v=cv), stats
 
 
-def _paged_attn(cfg, bp, x, q_pos, table, lengths, cache, *, window, rules,
-                impl, cow):
+def _paged_attn(cfg, bp, x, q_pos, table, lengths, cache, layer, *, window,
+                rules, impl):
     """The attention half of `block_decode_paged`: (x + attention, new k
-    pool, new v pool)."""
+    pools, new v pools)."""
     B, Q, _ = x.shape
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     P = table.shape[1]
     h_in = rms_norm(x, bp["ln1"])
     q, k, v = attn.qkv_project(cfg, bp["attn"], h_in, q_pos, rules=rules)
 
-    ck, cv = cache["k"], cache["v"]
-    if cow is not None:
-        # copy-on-write page break: move the shared page's payload into the
-        # slot's private copy before this step's rows land in it
-        cow_src, cow_dst = cow
-        ck = ck.at[cow_dst].set(ck[cow_src])
-        cv = cv.at[cow_dst].set(cv[cow_src])
-
-    # scatter the Q new K/V rows into their pages; tokens past a row's live
-    # length (padding / inactive rows) route to the reserved null page 0
+    # scatter the Q new K/V rows into their pages of this layer; tokens
+    # past a row's live length (padding / inactive rows) route to the
+    # reserved null page 0
     valid = q_pos < lengths[:, None]
     pidx = jnp.take_along_axis(table, jnp.minimum(q_pos // ps, P - 1), axis=1)
     pg = jnp.where(valid, jnp.maximum(pidx, 0), 0).reshape(-1)
     off = (q_pos % ps).reshape(-1)
-    ck = ck.at[pg, off].set(k.reshape((B * Q,) + k.shape[2:]))
-    cv = cv.at[pg, off].set(v.reshape((B * Q,) + v.shape[2:]))
+    ck = cache["k"].at[layer, pg, off].set(k.reshape(B * Q, -1))
+    cv = cache["v"].at[layer, pg, off].set(v.reshape(B * Q, -1))
 
     if impl == "pallas":
         kind, HP, g_pad = attn.head_layout(cfg)
@@ -501,16 +488,17 @@ def _paged_attn(cfg, bp, x, q_pos, table, lengths, cache, *, window, rules,
         # Q span along the row dim, position-major (row j*g_pad+g)
         qg = (q.reshape(B, Q, KVh, g_pad, hd)
               .transpose(0, 2, 1, 3, 4).reshape(B, KVh, Q * g_pad, hd))
-        ctx = paged_attention(qg, ck, cv, table, lengths, window=window,
-                              q_span=Q, q_start=q_pos[:, 0],
+        ctx = paged_attention(qg, ck, cv, layer, table, lengths,
+                              window=window, q_span=Q, q_start=q_pos[:, 0],
                               interpret=interpret_mode())
         _, hmask = attn.head_maps(cfg)
         ctx = (ctx.reshape(B, KVh, Q, g_pad, hd)
                .transpose(0, 2, 1, 3, 4).reshape(B, Q, HP, hd))
         ctx = ctx * hmask[None, None, :, None].astype(ctx.dtype)
     else:
-        kseq = attn.gather_pages(ck, table)
-        vseq = attn.gather_pages(cv, table)
+        kv_shape = k.shape[2:]
+        kseq = attn.gather_pages(ck, layer, table, kv_shape)
+        vseq = attn.gather_pages(cv, layer, table, kv_shape)
         k_pos = attn.paged_k_pos(lengths, P * ps)
         ctx = attn.decode_attention(cfg, q, kseq, vseq, q_pos, k_pos,
                                     window=window)

@@ -765,7 +765,7 @@ class ServeEngine:
                 NamedSharding(mesh, P(batch)))
 
     def _paged_sharding(self, mesh: Mesh):
-        """Paged pool (nb, n_pages, ps, kv, hd): shard the page dim when it
+        """Paged pool (nb, n_pages, ps, kv*hd): shard the page dim when it
         divides the mesh, else replicate."""
         ndev = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
         n_pages = jax.tree.leaves(self.blocks)[0].shape[1]
@@ -885,9 +885,8 @@ class ServeEngine:
 
         def build():
             def insert(blocks, rows_k, rows_v, page_ids):
-                def chop(rows):  # (nb, n, bucket, ...) -> (nb, n*bpp, ps, ...)
-                    return rows.reshape(rows.shape[0], n * bpp, ps,
-                                        *rows.shape[3:])
+                def chop(rows):  # (nb, n, bucket, kv, hd) -> pages
+                    return rows.reshape(rows.shape[0], n * bpp, ps, -1)
                 return {"k": blocks["k"].at[:, page_ids].set(chop(rows_k)),
                         "v": blocks["v"].at[:, page_ids].set(chop(rows_v))}
 
@@ -943,9 +942,9 @@ class ServeEngine:
     @property
     def _page_bytes(self) -> int:
         """Device bytes of one K+V page across the block stack."""
-        leaf = jax.tree.leaves(self.blocks)[0]  # (nb, N, ps, kv, hd)
-        nb, _, ps, kv, hd = leaf.shape
-        return 2 * nb * ps * kv * hd * leaf.dtype.itemsize
+        leaf = jax.tree.leaves(self.blocks)[0]  # (nb, N, ps, kv*hd)
+        nb, _, ps, lanes = leaf.shape
+        return 2 * nb * ps * lanes * leaf.dtype.itemsize
 
     def _insert(self, slots, blocks_rows, k_pos_rows) -> None:
         """Flat-layout scatter of prefilled rows into the pool at `slots`

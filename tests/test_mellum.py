@@ -152,7 +152,7 @@ def test_prefill_then_paged_decode_matches_reference(model):
     table = 1 + np.arange(B * P).reshape(B, P)
     ids = jnp.asarray(table[:, : bucket // ps].reshape(-1))
     pool = {k: pool[k].at[:, ids].set(
-        cache["blocks"][k].reshape(L, B * bucket // ps, ps, KV, hd))
+        cache["blocks"][k].reshape(L, B * bucket // ps, ps, KV * hd))
         for k in ("k", "v")}
     got = {b: [np.asarray(last[b, 0])] for b in range(B)}
     for t in range(steps):

@@ -144,27 +144,53 @@ PAGED_CASES = [
     (3, 2, 4, 32, 64, 26, 100, 1, (0, 1600, 700)),  # window < a block
     (3, 2, 2, 32, 64, 30, 0, 4, (0, 1026, 1537)),  # spans cross a boundary
     (3, 2, 2, 32, 64, 27, 150, 5, (0, 514, 1700)),  # spans + window
-    (2, 2, 4, 32, 64, 8, 0, 130, (0, 400)),  # a chunk: one kv head a step
+    (2, 2, 4, 32, 64, 8, 0, 130, (0, 400)),  # a chunk: one page a step
+    # danube's heads (8 x 80 lanes, no lane multiple each) and mellum's
+    # (4 x 128), over two blocks of 32 pages
+    (3, 8, 4, 80, 16, 40, 0, 1, (0, 333, 640)),
+    (3, 4, 8, 128, 16, 40, 200, 1, (0, 517, 640)),
+    (3, 8, 4, 80, 16, 40, 100, 3, (0, 512, 600)),
+    # a chunk whose rows take four steps
+    (2, 2, 4, 64, 64, 12, 0, 520, (0, 600)),
 ]
 
 
 @pytest.mark.parametrize("case", PAGED_CASES)
 def test_paged_attention_kernel_parity(case):
+    """Interpret-mode kernel vs the oracle, reading layer 1 of a 3-layer
+    stack of pools."""
     B, KV, G, hd, ps, P, window, Q, *fixed = case
-    heads, ppb = _block_sizes(ps, KV, Q * G, P)
-    if fixed:  # the multi-block cases must stay multi-block
+    rows, ppb = _block_sizes(ps, KV * Q * G, KV * hd, P)
+    if fixed and hd == 32:  # the multi-block cases must stay multi-block
         assert -(-P // ppb) >= 3
     if Q == 130:
-        assert heads == 1
-    q, kp, vp, table, lengths = random_case(case, np.random.default_rng(1))
+        assert ppb == 1
+    if Q == 520:
+        assert KV * Q * G // rows == 4
+    q, kp, vp, table, lengths = random_case(case, np.random.default_rng(1),
+                                            layers=3)
     lengths = lengths.at[0].set(0)  # inactive row must return zeros
-    out = paged_attention(q, kp, vp, table, lengths, window=window,
+    out = paged_attention(q, kp, vp, 1, table, lengths, window=window,
                           q_span=Q, interpret=True)
-    want = ref.paged_attention_ref(q, kp, vp, table, lengths,
+    want = ref.paged_attention_ref(q, kp, vp, 1, table, lengths,
                                    window=window, q_span=Q)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     assert np.all(np.asarray(out)[0] == 0.0)
+
+
+def test_paged_attention_reads_only_its_layer():
+    """Layer 0 of an L = 1 stack reads what the same pages as layer 2 of a
+    stack of 3 read; the other layers' pages change nothing."""
+    case = (2, 8, 4, 80, 16, 6, 0, 1, (40, 90))
+    q, kp, vp, table, lengths = random_case(case, np.random.default_rng(4),
+                                            layers=3)
+    out = paged_attention(q, kp, vp, 2, table, lengths, interpret=True)
+    one = paged_attention(q, kp[2:], vp[2:], 0, table, lengths,
+                          interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
+    other = paged_attention(q, kp, vp, 0, table, lengths, interpret=True)
+    assert not np.allclose(np.asarray(other), np.asarray(out))
 
 
 def test_fetch_plan_reads_only_owned_pages_and_repeats_past_the_tail():
@@ -198,14 +224,14 @@ def test_exact_parts_sum_back_to_float32():
 def test_paged_attention_kernel_bf16_pool():
     """A bfloat16 pool over three blocks matches the float32 oracle to the
     output's own rounding."""
-    P = 3 * _block_sizes(16, 8, 4, 1 << 10)[1] - 2  # danube's heads, Q=1
+    P = 3 * _block_sizes(16, 32, 640, 1 << 10)[1] - 2  # danube's, Q=1
     case = (2, 8, 4, 80, 16, P, 0, 1, (300, 16 * P - 5))
     q, kp, vp, table, lengths = random_case(case, np.random.default_rng(2),
                                             jnp.bfloat16)
-    out = paged_attention(q, kp, vp, table, lengths, interpret=True)
+    out = paged_attention(q, kp, vp, 0, table, lengths, interpret=True)
     with jax.default_matmul_precision("highest"):
         want = ref.paged_attention_ref(*(x.astype(jnp.float32)
-                                         for x in (q, kp, vp)),
+                                         for x in (q, kp, vp)), 0,
                                        table, lengths)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want), rtol=8e-3, atol=8e-3)
@@ -218,8 +244,8 @@ def test_paged_attention_q_span_matches_sequential_refs():
     B, KV, G, hd, ps, P, Q = 2, 2, 3, 16, 4, 6, 3
     N = B * P + 1
     q = jnp.asarray(rng.standard_normal((B, KV, Q * G, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((N, ps, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((1, N, ps, KV * hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((1, N, ps, KV * hd)), jnp.float32)
     lengths = np.array([Q + 5, P * ps], np.int32)
     table = np.full((B, P), -1, np.int32)
     perm = rng.permutation(np.arange(1, N))
@@ -228,12 +254,13 @@ def test_paged_attention_q_span_matches_sequential_refs():
         n = -(-int(lengths[b]) // ps)
         table[b, :n] = perm[used: used + n]
         used += n
-    span = ref.paged_attention_ref(q, kp, vp, jnp.asarray(table),
+    span = ref.paged_attention_ref(q, kp, vp, 0, jnp.asarray(table),
                                    jnp.asarray(lengths), q_span=Q)
     for j in range(Q):
         qj = q.reshape(B, KV, Q, G, hd)[:, :, j]
         lj = jnp.asarray(lengths - (Q - 1 - j), jnp.int32)
-        one = ref.paged_attention_ref(qj, kp, vp, jnp.asarray(table), lj)
+        one = ref.paged_attention_ref(qj, kp, vp, 0, jnp.asarray(table),
+                                      lj)
         np.testing.assert_allclose(
             np.asarray(span.reshape(B, KV, Q, G, hd)[:, :, j]),
             np.asarray(one), rtol=2e-5, atol=2e-5)
@@ -420,6 +447,21 @@ def test_paged_admission_bytes_are_page_proportional(cfg):
     assert pb == pages_written * paged._page_bytes
     # flat rewrites the whole pool per admission group; paged is a fraction
     assert pb < fb / 4, (pb, fb)
+
+
+def test_page_bytes_count_the_lane_dense_pool(cfg):
+    """The pools are (L, N, page_size, KV*hd); a page's K+V bytes across
+    the stack are what one page of both pools holds, the same count as
+    the (L, N, page_size, KV, hd) layout they replace."""
+    eng = ServeEngine(cfg, capacity=2, cache_len=32, prefill_bucket=8,
+                      n_workers=1, seed=0, kv_layout="paged", page_size=8)
+    L, KV, hd = cfg.num_layers, cfg.kv_heads(), cfg.head_dim_()
+    n_pages = 2 * 32 // 8 + 1
+    for pool in eng.blocks.values():
+        assert pool.shape == (L, n_pages, 8, KV * hd)
+    item = jnp.dtype(cfg.dtype).itemsize
+    assert eng._page_bytes == 2 * L * 8 * KV * hd * item
+    assert eng._page_bytes * n_pages == eng._pool_bytes
 
 
 # ---------------------------------------------------------------------------
