@@ -8,6 +8,7 @@ from .model import (
     init_cache,
     init_params,
     loss_fn,
+    paged_step,
     param_defs,
     param_sds,
     param_specs,
@@ -24,6 +25,7 @@ __all__ = [
     "init_cache",
     "init_params",
     "loss_fn",
+    "paged_step",
     "param_defs",
     "param_sds",
     "param_specs",

@@ -90,76 +90,70 @@ def _logits(cfg, params, x):
 
 
 def _scan_layers(cfg: ModelConfig, window: Optional[int], make_body, x,
-                 blocks, state=None, whole_state: bool = False):
-    """Run the stacked layers over x.  `make_body(layer_cfg, window)` gives
-    `body(x, (layer params, layer state)) -> (x, (new layer state,
-    extras))`; `blocks`, `state` and the extras are stacked over layers
-    (`state` may be None).  Returns (x, new state, extras).
+                 blocks, *, state=None, pools=None):
+    """Run the stacked layers over x: one scan of a period of layers a step
+    (`tfm.layer_kinds`; a model of one kind is a period of one), the
+    period's layers unrolled, each traced with its own window and RoPE.
+    `make_body(layer_cfg, window)` gives `body(x, (layer params, s)) ->
+    (x, (new s, extras))`; `blocks` and the extras are stacked over layers.
 
-    One kind of attention (`tfm.layer_kinds`): a scan of one block a step.
-    Per-layer kinds, or an MoE model: a scan of one period a step (one
-    layer for an MoE model of one kind), its layers unrolled, each traced
-    with its own window and RoPE; the state rides in the carry and each
-    layer's slice is updated in place.  An MoE layer's expert weights stay
-    whole, with the layer's index beside them: the grouped kernel reads
-    the layer's experts in place (a slice per step would copy them all).
+    state: per-layer state stacked over layers, or None.  `s` is the
+    layer's own slice; the state is scanned in and out like the blocks.
+    pools: state that every layer reads and writes whole (the paged KV
+    pools).  `s` is `(pools, layer index)`; the pools ride in the carry and
+    are never sliced per layer.  Returns (x, new state or new pools,
+    extras).
 
-    whole_state: the body takes `(layer params, (whole state, layer
-    index))` and returns the whole new state, which rides in the carry of
-    a scan of one period a step (a period of one layer for one kind): the
-    state is never sliced per layer (the paged KV pools, which each layer
-    reads and writes by its index).
-    """
+    An MoE layer's expert weights stay whole, with the layer's index beside
+    them: the grouped kernel reads the layer's experts in place (a slice
+    per step would copy them all)."""
     kinds = tfm.layer_kinds(cfg, window)
+    P = len(kinds)
+    bodies = [make_body(*k) for k in kinds]
     experts = None
     if cfg.family == "moe":
         moe = dict(blocks["moe"])
         experts = {k: moe.pop(k) for k in ("gate", "up", "down")}
         blocks = dict(blocks, moe=moe)
-    elif len(kinds) == 1 and not whole_state:
-        body = make_body(*kinds[0])
-        if state is None:
-            def body_nc(x, bp):
-                x, (_, ex) = body(x, (bp, None))
-                return x, ex
-            x, extras = jax.lax.scan(body_nc, x, blocks)
-            return x, None, extras
-        x, (state, extras) = jax.lax.scan(body, x, (blocks, state))
-        return x, state, extras
 
-    P = len(kinds)
-    bodies = [make_body(*k) for k in kinds]
+    # stacks over layers are grouped (L/P, P, ...), a period a scan step,
+    # and layer i of a period is [i] of it.  A period of one keeps (L, ...):
+    # on a TPU an (L, 1, D) view of an (L, D) stack is a relayout, not a
+    # bitcast.
+    def group(t):
+        return t if P == 1 else jax.tree.map(
+            lambda a: a.reshape((-1, P) + a.shape[1:]), t)
 
-    def period(carry, bp):
-        x, st, j = carry
-        exs = []
+    def ungroup(t):
+        return t if P == 1 else jax.tree.map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), t)
+
+    def layer_of(t, i):
+        return t if P == 1 else jax.tree.map(lambda a: a[i], t)
+
+    def period(carry, xs):
+        x, pl, j = carry
+        bp, st = xs
+        outs = []
         for i, body in enumerate(bodies):
             layer = j * P + i
-            if whole_state:
-                sl = (st, layer)
-            else:
-                sl = None if st is None else jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
-                                                           False), st)
-            lp = jax.tree.map(lambda a: a[i], bp)
+            lp = layer_of(bp, i)
             if experts is not None:
                 lp = dict(lp, moe=dict(lp["moe"], layer=layer, **experts))
-            x, (new_sl, ex) = body(x, (lp, sl))
-            if whole_state:
-                st = new_sl
-            elif st is not None:
-                st = jax.tree.map(
-                    lambda a, u: jax.lax.dynamic_update_index_in_dim(
-                        a, u.astype(a.dtype), layer, 0), st, new_sl)
-            exs.append(ex)
-        return (x, st, j + 1), jax.tree.map(lambda *e: jnp.stack(e), *exs)
+            if pools is None:
+                x, out = body(x, (lp, layer_of(st, i)))
+            else:
+                x, (pl, ex) = body(x, (lp, (pl, layer)))
+                out = (None, ex)
+            outs.append(out)
+        outs = outs[0] if P == 1 else jax.tree.map(
+            lambda *o: jnp.stack(o), *outs)
+        return (x, pl, j + 1), outs
 
-    grouped = jax.tree.map(
-        lambda a: a.reshape((a.shape[0] // P, P) + a.shape[1:]), blocks)
-    (x, state, _), extras = jax.lax.scan(period, (x, state, jnp.int32(0)),
-                                         grouped)
-    extras = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), extras)
-    return x, state, extras
+    (x, pools, _), (state, extras) = jax.lax.scan(
+        period, (x, pools, jnp.int32(0)), group((blocks, state)))
+    state, extras = ungroup((state, extras))
+    return x, (state if pools is None else pools), extras
 
 
 def _sum_stats(stats):
@@ -245,7 +239,7 @@ def _forward(cfg: ModelConfig, params, tokens: jax.Array, *,
         caches = jax.tree.map(
             lambda a: jnp.broadcast_to(a[None], (nb,) + a.shape), dummy)
     x, cache, (auxs, stats) = _scan_layers(cfg, window, make_body, x,
-                                           params["blocks"], caches)
+                                           params["blocks"], state=caches)
 
     logits = _logits(cfg, params, x)
     aux = jnp.sum(auxs) if auxs is not None else jnp.float32(0.0)
@@ -366,7 +360,7 @@ def decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
         return body
 
     x, new_blocks, _ = _scan_layers(cfg, window, make_body, x,
-                                    params["blocks"], cache["blocks"])
+                                    params["blocks"], state=cache["blocks"])
     logits = _logits(cfg, params, x)
     new_cache = dict(cache, blocks=new_blocks)
     if k_pos is not None:
@@ -386,7 +380,7 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int,
     layout the paged kernel reads and a TPU lays out unpadded.  Page 0 is
     the engine's reserved null page (masked writes land there).  Per-slot
     fill depth lives in the block table + lengths the caller threads
-    through `paged_decode_step`; there is no device-side k_pos state.
+    through `paged_step`; there is no device-side k_pos state.
     dense/moe only (flat recurrent state does not page)."""
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
@@ -408,38 +402,38 @@ def _cow_pages(blocks, cow):
     return {k: a.at[:, dst].set(a[:, src]) for k, a in blocks.items()}
 
 
-def paged_decode_step(cfg: ModelConfig, params, cache, token: jax.Array,
-                      pos: jax.Array, table: jax.Array, lengths: jax.Array,
-                      *, rules: AxisRules, window: Optional[int] = None,
-                      impl: str = "xla",
-                      cow: Optional[Tuple[jax.Array, jax.Array]] = None,
-                      stats: bool = False):
-    """One paged decode step.  token: (B, 1) int32; pos: (B,) per-row write
-    positions; table: (B, P) block table; lengths: (B,) live tokens incl.
-    this one (0 = inactive row, output garbage, writes -> null page).
+def paged_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
+               pos: jax.Array, table: jax.Array, lengths: jax.Array, *,
+               rules: AxisRules, window: Optional[int] = None,
+               impl: str = "xla",
+               cow: Optional[Tuple[jax.Array, jax.Array]] = None,
+               stats: bool = False):
+    """One paged step over a span of Q new tokens a row, in one dispatch:
+    a decode step (Q = 1), a speculative verify (Q = k + 1: the current
+    token and k drafts) or one chunk of a chunked prefill (Q = the chunk).
+
+    tokens: (B, Q) int32, right-padded; pos: (B,) absolute position of each
+    row's first token; table: (B, P) block table; lengths: (B,) live tokens,
+    the span's real ones included (0 = inactive row: its output is garbage
+    and its writes go to the null page).  Each token attends the row's
+    paged context plus the span's earlier tokens (causal by absolute
+    position), and its K/V rows are written into their pages in place
+    (`transformer.block_decode_paged`); padding past a row's length is
+    causally invisible to its real tokens.
 
     cow: optional ((B,), (B,)) int32 (src, dst) page pairs — copy-on-write
-    share breaks fused into the step (`_cow_pages`; rows without a break
-    pass the null page for both).
+    share breaks fused into the step (`_cow_pages`; only a span's first
+    page can be shared, so one pair a row suffices).
 
-    Returns (logits (B, 1, V), new cache), and with `stats` (MoE models)
+    Returns (logits (B, Q, V), new cache), and with `stats` (MoE models)
     the (moe.N_STATS,) int32 expert counters summed over layers."""
-    x = _embed(cfg, params, token)
+    Q = tokens.shape[1]
+    x = _embed(cfg, params, tokens)
     if rules is not None:
         x = jax.lax.with_sharding_constraint(
             x, rules.sharding("batch", None, None))
-    q_pos = pos.astype(jnp.int32)[:, None]
-    x, new_blocks, moe = _scan_layers(
-        cfg, window, _paged_body(q_pos, table, lengths, rules, impl), x,
-        params["blocks"], _cow_pages(cache["blocks"], cow), whole_state=True)
-    out = (_logits(cfg, params, x), dict(cache, blocks=new_blocks))
-    return out + (_sum_stats(moe),) if stats else out
+    q_pos = pos.astype(jnp.int32)[:, None] + jnp.arange(Q, dtype=jnp.int32)
 
-
-def _paged_body(q_pos, table, lengths, rules, impl):
-    """`make_body` for `_scan_layers(whole_state=True)` over
-    `transformer.block_decode_paged`: each layer gets the whole pools and
-    its index."""
     def make_body(lcfg, win):
         def body(x, xs):
             bp, (pools, layer) = xs
@@ -448,40 +442,12 @@ def _paged_body(q_pos, table, lengths, rules, impl):
                 window=win, rules=rules, impl=impl)
             return x, (new_pools, moe)
         return body
-    return make_body
 
-
-def paged_prefill_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
-                        offset: jax.Array, chunk_end: jax.Array,
-                        table: jax.Array, *, rules: AxisRules,
-                        window: Optional[int] = None,
-                        impl: str = "xla"
-                        ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One chunk of an incremental (chunked) prefill.
-
-    tokens: (B, C) the next C prompt tokens of each row, right-padded;
-    offset: (B,) absolute position of each row's first chunk token;
-    chunk_end: (B,) live length after this chunk (offset + real chunk
-    tokens; 0 marks an inactive row).  Chunk q attends the row's previously
-    paged context plus itself (causal by absolute position), and the
-    chunk's K/V pages are written in place — O(chunk) work per call, so a
-    long prompt amortizes over many engine ticks instead of stalling one.
-
-    Returns (last-token logits (B, 1, V), new cache)."""
-    B, C = tokens.shape
-    x = _embed(cfg, params, tokens)
-    if rules is not None:
-        x = jax.lax.with_sharding_constraint(
-            x, rules.sharding("batch", None, None))
-    q_pos = (offset.astype(jnp.int32)[:, None]
-             + jnp.arange(C, dtype=jnp.int32)[None])
-    x, new_blocks, _ = _scan_layers(
-        cfg, window, _paged_body(q_pos, table, chunk_end, rules, impl), x,
-        params["blocks"], cache["blocks"], whole_state=True)
-    logits = _logits(cfg, params, x)
-    last = jnp.clip(chunk_end - offset - 1, 0, C - 1).astype(jnp.int32)
-    last_logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)
-    return last_logits, dict(cache, blocks=new_blocks)
+    x, new_blocks, moe = _scan_layers(
+        cfg, window, make_body, x, params["blocks"],
+        pools=_cow_pages(cache["blocks"], cow))
+    out = (_logits(cfg, params, x), dict(cache, blocks=new_blocks))
+    return out + (_sum_stats(moe),) if stats else out
 
 
 # ---------------------------------------------------------------------------
@@ -539,40 +505,9 @@ def verify_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
         return body
 
     x, new_blocks, _ = _scan_layers(cfg, window, make_body, x,
-                                    params["blocks"], cache["blocks"])
+                                    params["blocks"], state=cache["blocks"])
     logits = _logits(cfg, params, x)
     return logits, dict(cache, blocks=new_blocks, k_pos=k_pos)
-
-
-def paged_verify_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
-                      pos: jax.Array, table: jax.Array, lengths: jax.Array,
-                      *, rules: AxisRules, window: Optional[int] = None,
-                      impl: str = "xla",
-                      cow: Optional[Tuple[jax.Array, jax.Array]] = None
-                      ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """Paged-layout speculative verification: the (B, Q) span twin of
-    `paged_decode_step`, scoring all draft positions through
-    `transformer.block_decode_paged` (XLA gather or the Pallas paged kernel
-    with q_span=Q) in a single dispatch.
-
-    tokens: (B, Q) int32 — [current token, draft_1..draft_{Q-1}] per row;
-    pos: (B,) absolute position of each row's first token; table: (B, P)
-    block table; lengths: (B,) live tokens INCLUDING the span's real tokens
-    (pos + n_new; 0 = inactive row).  Draft padding past a row's length
-    routes its writes to the null page and is causally invisible to valid
-    positions.  cow: optional (src, dst) copy-on-write page pairs (only the
-    span's FIRST page can be shared, so one pair per row suffices).
-    Returns (logits (B, Q, V), new cache)."""
-    B, Q = tokens.shape
-    x = _embed(cfg, params, tokens)
-    if rules is not None:
-        x = jax.lax.with_sharding_constraint(
-            x, rules.sharding("batch", None, None))
-    q_pos = pos.astype(jnp.int32)[:, None] + jnp.arange(Q, dtype=jnp.int32)
-    x, new_blocks, _ = _scan_layers(
-        cfg, window, _paged_body(q_pos, table, lengths, rules, impl), x,
-        params["blocks"], _cow_pages(cache["blocks"], cow), whole_state=True)
-    return _logits(cfg, params, x), dict(cache, blocks=new_blocks)
 
 
 def prefill(cfg: ModelConfig, params, tokens: jax.Array, *,

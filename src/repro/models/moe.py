@@ -97,7 +97,8 @@ def moe_ffn(cfg: ModelConfig, p, x: jax.Array, *,
                         unique_indices=True)  # (T*K, D)
 
         # the expert weights come whole, (L, held, ...), with the layer
-        # index under "layer" (see `model._scan_layers`), or as one layer's
+        # index under "layer" (`model._scan_layers` passes them so), or as
+        # one layer's (held, ...)
         def matmul(lhs, w):
             if "layer" not in p:
                 w = w[None]

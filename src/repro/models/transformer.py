@@ -11,7 +11,7 @@ block):
 A config with per-layer attention kinds (`layer_windows`: each layer's
 window, 0 = full attention with its own RoPE) keeps one block per layer;
 the model scans one period of them a step (`layer_kinds`,
-`model._scan_layers`).
+`model._scan_layers`; a model of one kind is a period of one).
   hybrid:  `attn_every` sub-layers: 1 attn + (attn_every-1) mamba, ffn
            alternating dense/moe per `moe_every`
   vlm:     (cross_attn_every-1) x (self+mlp) + 1 x (cross-attn+mlp)
@@ -275,6 +275,20 @@ def cross_kv(cfg, p, memory):
     return k, v
 
 
+def _ffn(cfg, bp, x, rules, valid=None):
+    """The dense/MoE block's feed-forward half: (x + ffn(rms_norm(x)), the
+    MoE aux loss (0 for a dense block), moe stats (moe; else None)).
+    valid: optional bool mask of real tokens (an MoE layer computes no
+    expert rows for the others)."""
+    h = rms_norm(x, bp["ln2"])
+    if "moe" in bp:
+        f, aux, stats = moe_mod.moe_ffn(cfg, bp["moe"], h, valid=valid)
+        return x + f, aux, stats
+    m = bp["mlp"]
+    f = swiglu(h, m["gate"], m["up"], m["down"], rules)
+    return x + f, jnp.float32(0.0), None
+
+
 # ---------------------------------------------------------------------------
 # Block apply: train/prefill unified (cache=None -> train)
 # ---------------------------------------------------------------------------
@@ -298,13 +312,7 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
         h, k, v = _self_attn_full(cfg, bp["attn"], rms_norm(x, bp["ln1"]),
                                   positions, window=window, causal=causal,
                                   q_block=q_block, rules=rules)
-        x = x + h
-        h2 = rms_norm(x, bp["ln2"])
-        if bt == "moe":
-            f, aux, stats = moe_mod.moe_ffn(cfg, bp["moe"], h2, valid=valid)
-        else:
-            f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"], rules)
-        x = x + f
+        x, aux, stats = _ffn(cfg, bp, x + h, rules, valid)
         new_cache = None if cache is None else dict(cache, k=k, v=v)
         return x, new_cache, aux, stats
 
@@ -416,7 +424,7 @@ def block_apply(cfg: ModelConfig, bp, x, positions, *, block_type=None,
 
 
 # ---------------------------------------------------------------------------
-# Block apply: paged decode / chunked prefill (token positions -> pages)
+# Block apply: paged decode / verify / prefill chunk (positions -> pages)
 # ---------------------------------------------------------------------------
 
 
@@ -424,7 +432,8 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
                        layer, *, window=0, rules: AxisRules = None,
                        impl="xla"):
     """Paged-KV block step over new tokens x: (B, Q, D) at positions
-    q_pos: (B, Q).  Q == 1 is decode; Q > 1 is one chunked-prefill chunk.
+    q_pos: (B, Q), the layer of `model.paged_step`: Q == 1 is decode; Q > 1
+    a speculative verify span or one chunked-prefill chunk.
 
     cache: {"k": (L, N, ps, KV*hd), "v": ...} every layer's physical page
     pools, shared by every sequence, of which this block reads and writes
@@ -445,15 +454,8 @@ def block_decode_paged(cfg: ModelConfig, bp, x, q_pos, table, lengths, cache,
     with _attn_scope(window):
         x, ck, cv = _paged_attn(cfg, bp, x, q_pos, table, lengths, cache,
                                 layer, window=window, rules=rules, impl=impl)
-    h2 = rms_norm(x, bp["ln2"])
-    stats = None
-    if bt == "moe":
-        f, _, stats = moe_mod.moe_ffn(cfg, bp["moe"], h2,
-                                      valid=q_pos < lengths[:, None])
-    else:
-        f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"],
-                   rules)
-    return x + f, dict(cache, k=ck, v=cv), stats
+    x, _, stats = _ffn(cfg, bp, x, rules, q_pos < lengths[:, None])
+    return x, dict(cache, k=ck, v=cv), stats
 
 
 def _paged_attn(cfg, bp, x, q_pos, table, lengths, cache, layer, *, window,
@@ -544,13 +546,8 @@ def block_verify(cfg: ModelConfig, bp, x, q_pos, valid, k_pos, cache, *,
         ctx = attn.decode_attention(cfg, q, ck, cv, q_pos, k_pos,
                                     window=window)
         x = x + attn.attn_out(bp["attn"], ctx, rules)
-    h2 = rms_norm(x, bp["ln2"])
-    if bt == "moe":
-        f, _, _ = moe_mod.moe_ffn(cfg, bp["moe"], h2, valid=valid)
-    else:
-        f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"],
-                   rules)
-    return x + f, dict(cache, k=ck, v=cv)
+    x, _, _ = _ffn(cfg, bp, x, rules, valid)
+    return x, dict(cache, k=ck, v=cv)
 
 
 # ---------------------------------------------------------------------------
@@ -569,13 +566,8 @@ def block_decode(cfg: ModelConfig, bp, x, pos, k_pos, cache, *,
                                       pos, k_pos, cache["k"], cache["v"],
                                       window=window, ring=ring, rules=rules)
         new_cache["k"], new_cache["v"] = nk, nv
-        x = x + h
-        h2 = rms_norm(x, bp["ln2"])
-        if bt == "moe":
-            f, _, _ = moe_mod.moe_ffn(cfg, bp["moe"], h2)
-        else:
-            f = swiglu(h2, bp["mlp"]["gate"], bp["mlp"]["up"], bp["mlp"]["down"], rules)
-        return x + f, new_cache
+        x, _, _ = _ffn(cfg, bp, x + h, rules)
+        return x, new_cache
 
     if bt == "hybrid":
         k_sub = cfg.attn_every

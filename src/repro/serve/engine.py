@@ -710,7 +710,7 @@ class ServeEngine:
 
             def decode(params, blocks, meta):
                 tok, pos, table, lengths, cow_src, cow_dst = unpack(meta, 1)
-                logits, new_cache, *moe = M.paged_decode_step(
+                logits, new_cache, *moe = M.paged_step(
                     cfg, params, {"blocks": blocks}, tok, pos, table,
                     lengths, rules=rules, impl=impl,
                     cow=(cow_src, cow_dst) if use_cow else None,
@@ -722,7 +722,7 @@ class ServeEngine:
 
             def verify(params, blocks, meta, q):
                 tok, pos, table, lengths, cow_src, cow_dst = unpack(meta, q)
-                logits, new_cache = M.paged_verify_step(
+                logits, new_cache = M.paged_step(
                     cfg, params, {"blocks": blocks}, tok, pos, table,
                     lengths, rules=rules, impl=impl,
                     cow=(cow_src, cow_dst) if use_cow else None)
@@ -921,9 +921,13 @@ class ServeEngine:
                 offset = meta[:nb]
                 chunk_end = meta[nb: 2 * nb]
                 table = meta[2 * nb:].reshape(nb, -1)
-                last, new_cache = M.paged_prefill_chunk(
-                    cfg, params, {"blocks": blocks}, tokens, offset,
-                    chunk_end, table, rules=rules, impl=impl)
+                logits, new_cache = M.paged_step(
+                    cfg, params, {"blocks": blocks}, tokens, offset, table,
+                    chunk_end, rules=rules, impl=impl)
+                # each row's logits at its last live chunk token
+                at = jnp.clip(chunk_end - offset - 1, 0,
+                              tokens.shape[1] - 1).astype(jnp.int32)
+                last = jnp.take_along_axis(logits, at[:, None, None], axis=1)
                 nxt = jnp.argmax(last[:, -1], -1).astype(jnp.int32)
                 return nxt, new_cache["blocks"]
 

@@ -24,7 +24,7 @@ the acceptance rate:
   (batched prefill over all active slots + k-1 vectorized decode steps).
   Draft params reshard with the engine on `resize(k)`.
 
-The engine verifies drafts through `models.model.paged_verify_step` /
+The engine verifies drafts through `models.model.paged_step` /
 `verify_step` (one (B, Q=k+1) dispatch) and calls `greedy_accept` per slot.
 """
 from __future__ import annotations

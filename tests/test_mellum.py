@@ -157,7 +157,7 @@ def test_prefill_then_paged_decode_matches_reference(model):
     got = {b: [np.asarray(last[b, 0])] for b in range(B)}
     for t in range(steps):
         pos = lens + t
-        logits, new, _ = M.paged_decode_step(
+        logits, new, _ = M.paged_step(
             cfg, params, {"blocks": pool},
             jnp.asarray(seq[np.arange(B), pos][:, None]), jnp.asarray(pos),
             jnp.asarray(table), jnp.asarray(pos + 1), rules=None,

@@ -120,12 +120,17 @@ def test_expert_matmul_compiles_for_v5e(name, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in text and "%expert_matmul" in text
 
 
-# (config, slots, pages a slot) of each serving cell's decode program:
-# danube-serve-long (22 slots of 4,096 tokens) and mellum-serve-long (16
-# slots, the registered config's 16 held experts), pages of 16 tokens
+# (config, rows, pages a row, span Q, whether a prefill chunk) of each use
+# of the paged step: the serving cells' decode programs, danube-serve-long
+# (22 slots of 4,096 tokens) and mellum-serve-long (16 slots, the
+# registered config's 16 held experts); danube's speculative verify of 4
+# drafts; a 256-token chunk of a chunked prefill over 4 rows.  Pages of 16
+# tokens
 _DECODE_CELLS = {
-    "danube_serve_long": (_DANUBE, 22, 256),
-    "mellum_serve_long": (_MELLUM, 16, 256),
+    "danube_serve_long": (_DANUBE, 22, 256, 1, False),
+    "danube_verify_q5": (_DANUBE, 22, 256, 5, False),
+    "danube_chunk256": (_DANUBE, 4, 256, 256, True),
+    "mellum_serve_long": (_MELLUM, 16, 256, 1, False),
 }
 _PAGE = 16
 _DTYPE_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4,
@@ -159,16 +164,17 @@ def _pool_moves(text: str, n_pages: int, limit: int) -> list:
 def test_paged_decode_keeps_the_pool_in_place_on_v5e(name, one_chip,
                                                      no_persistent_cache,
                                                      monkeypatch):
-    """The engine's paged Pallas decode step at a serving cell's shapes,
-    compiled for a v5e: the K/V pools keep one unpadded row-major layout
-    from entry to exit, both alias their donated inputs, nothing copies,
-    slices or update-slices an array of pages of one layer's pool size or
-    more, and the step's temporaries stay under 1 GB."""
+    """The engine's paged Pallas step (decode, verify or a prefill chunk)
+    at a serving cell's shapes, compiled for a v5e: the K/V pools keep one
+    unpadded row-major layout from entry to exit, both alias their donated
+    inputs, nothing copies, slices or update-slices an array of pages of
+    one layer's pool size or more, and the step's temporaries stay under
+    1 GB."""
     import repro.kernels.ops as ops
     from repro.models import model as M
 
     monkeypatch.setattr(ops, "interpret_mode", lambda: False)
-    cfg, B, P = _DECODE_CELLS[name]
+    cfg, B, P, Q, is_chunk = _DECODE_CELLS[name]
     N = B * P + 1
 
     def sds(shape, dtype):
@@ -180,18 +186,36 @@ def test_paged_decode_keeps_the_pool_in_place_on_v5e(name, one_chip,
     stats = cfg.family == "moe"
 
     def decode(params, blocks, meta):  # as `ServeEngine._build` packs it
-        tok, pos = meta[:B].reshape(B, 1), meta[B:2 * B]
-        table = meta[2 * B:B * (2 + P)].reshape(B, P)
-        lengths = meta[B * (2 + P):B * (3 + P)]
-        cow = (meta[B * (3 + P):B * (4 + P)], meta[B * (4 + P):])
-        logits, new, *moe = M.paged_decode_step(
+        tok = meta[:B * Q].reshape(B, Q)
+        pos = meta[B * Q:B * (Q + 1)]
+        table = meta[B * (Q + 1):B * (Q + 1 + P)].reshape(B, P)
+        lengths = meta[B * (Q + 1 + P):B * (Q + 2 + P)]
+        cow = (meta[B * (Q + 2 + P):B * (Q + 3 + P)], meta[B * (Q + 3 + P):])
+        logits, new, *moe = M.paged_step(
             cfg, params, {"blocks": blocks}, tok, pos, table, lengths,
-            rules=None, impl="pallas", cow=cow, stats=stats)
+            rules=None, impl="pallas", cow=cow, stats=stats and Q == 1)
+        if Q > 1:  # a verify returns every position's argmax
+            return jnp.argmax(logits, -1).astype(jnp.int32), new["blocks"]
         nxt = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
         return jnp.concatenate([nxt, *moe]), new["blocks"]
 
-    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
-        params, pools, sds((B * (5 + P),), jnp.int32)).compile()
+    def chunk(params, blocks, tokens, meta):  # as `ServeEngine._chunk_fn`
+        offset, chunk_end = meta[:B], meta[B:2 * B]
+        table = meta[2 * B:].reshape(B, P)
+        logits, new = M.paged_step(
+            cfg, params, {"blocks": blocks}, tokens, offset, table,
+            chunk_end, rules=None, impl="pallas")
+        at = jnp.clip(chunk_end - offset - 1, 0, Q - 1).astype(jnp.int32)
+        last = jnp.take_along_axis(logits, at[:, None, None], axis=1)
+        return jnp.argmax(last[:, -1], -1).astype(jnp.int32), new["blocks"]
+
+    if is_chunk:
+        compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+            params, pools, sds((B, Q), jnp.int32),
+            sds((B * (2 + P),), jnp.int32)).compile()
+    else:
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, pools, sds((B * (Q + 4 + P),), jnp.int32)).compile()
     text = compiled.as_text()
     pool = pools["k"]
     L, _, ps, lanes = pool.shape
